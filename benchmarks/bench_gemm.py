@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from benchmarks.common import row, time_jitted, write_bench_json
 from repro.core.blockspec import derive_tiling, pick_tile
 from repro.kernels import programs, ref as kref
+from repro.launch.compile_cache import enable_compile_cache
 
 # (name, M(batch), K, N) — paper weight shapes / 4
 SHAPES = [
@@ -147,6 +148,7 @@ def main(argv=None) -> None:
     g.add_argument("--program", dest="program_", action="store_true",
                    help="DSL-vs-legacy-shim comparison; writes BENCH_kernels.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     rows = run_program_mode() if args.program_ else \

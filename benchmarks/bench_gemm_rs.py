@@ -1,7 +1,8 @@
 """Paper Fig. 12 — GEMM + Reduce-Scatter: fused/overlapped vs unfused.
 
-Runs in a subprocess with 8 host-platform devices (so the main process
-and other benches keep seeing 1 device). Compares:
+Runs in a CPU-only subprocess with 8 host-platform devices (so the main
+process and other benches keep their own devices, and the child never
+competes for a chip the parent holds). Rows are CPU timings. Compares:
   * unfused — the collective_matmul program's psum_scatter variant
     (full local GEMM then reduce-scatter, the cuBLAS+NCCL analogue)
   * fused   — the ring variant of the same program stage
@@ -38,7 +39,7 @@ def run(mode):
         return programs.collective_matmul(
             a, b, axis_name="model",
             impl="ring" if mode == "fused" else "psum_scatter")
-    f = jax.jit(compat.shard_map(body, mesh=mesh,
+    f = jax.jit(jax.shard_map(body, mesh=mesh,
                 in_specs=(P(None, "model"), P("model", None)),
                 out_specs=P("model", None), check_vma=False))
     out = f(a, b); jax.block_until_ready(out)
@@ -67,6 +68,7 @@ print(json.dumps({"us_unfused": us_u, "us_fused": us_f, "err": err,
 
 def run() -> list:
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(os.path.dirname(__file__), "..", "src"),
          os.path.dirname(os.path.dirname(__file__))] + sys.path
@@ -75,11 +77,14 @@ def run() -> list:
         [sys.executable, "-c", _CHILD], capture_output=True, text=True, env=env
     )
     if out.returncode != 0:
-        return [row("gemm_rs.error", 0.0, out.stderr.strip()[-120:].replace(",", ";"))]
+        raise RuntimeError(
+            f"gemm_rs child failed (exit {out.returncode}):\n{out.stderr[-2000:]}"
+        )
     data = json.loads(out.stdout.strip().splitlines()[-1])
     return [
-        row("gemm_rs.unfused", data["us_unfused"], "full GEMM + psum_scatter"),
+        row("gemm_rs.unfused", data["us_unfused"],
+            "cpu 8 host devices; full GEMM + psum_scatter"),
         row("gemm_rs.fused", data["us_fused"],
-            f"ring overlap; err={data['err']:.1e}; plan={'+'.join(data['plan'])}"
-            f"; plan_bytes={data['plan_bytes']}"),
+            f"cpu 8 host devices; ring overlap; err={data['err']:.1e}; "
+            f"plan={'+'.join(data['plan'])}; plan_bytes={data['plan_bytes']}"),
     ]

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.common import row, time_jitted, write_bench_json
+from repro.launch.compile_cache import enable_compile_cache
 
 BENCH_GRAPH_JSON = "BENCH_graph.json"
 
@@ -316,6 +317,7 @@ def main() -> int:
                          "exports the run's measured schedules to "
                          "bench_out/schedule_service.json")
     args = ap.parse_args()
+    enable_compile_cache()
     rows = run(args.batch, args.seq, fuse=not args.no_fuse)
     if args.offload:
         rows += run_offload(args.batch, args.seq)

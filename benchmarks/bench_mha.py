@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from benchmarks.common import row, time_jitted, write_bench_json
 from repro.kernels import programs, ref as kref
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import attention as attn_mod
 
 LENS = [512, 1024, 2048]
@@ -123,6 +124,7 @@ def main(argv=None) -> None:
     g.add_argument("--program", dest="program_", action="store_true",
                    help="DSL-vs-legacy-shim comparison; appends to BENCH_kernels.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     rows = run_program_mode() if args.program_ else \

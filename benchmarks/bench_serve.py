@@ -29,6 +29,7 @@ if __package__ in (None, ""):  # script mode: make `benchmarks.*` importable
 import numpy as np
 
 from benchmarks.common import row, write_bench_json
+from repro.launch.compile_cache import enable_compile_cache
 
 BENCH_SERVE_JSON = "BENCH_serve.json"
 
@@ -112,6 +113,7 @@ def main() -> int:
     ap.add_argument("--new-tokens", type=int, default=8)
     ap.add_argument("--archs", type=str, default=",".join(ARCHS))
     args = ap.parse_args()
+    enable_compile_cache()
     rows = run(args.slots, args.max_seq, args.requests, args.new_tokens,
                tuple(a for a in args.archs.split(",") if a))
     path = write_bench_json("serve", rows, filename=BENCH_SERVE_JSON)

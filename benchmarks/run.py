@@ -12,6 +12,9 @@ import sys
 
 def main() -> None:
     from benchmarks import bench_gemm, bench_gemm_rs, bench_layout, bench_mha, bench_moe
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     for mod in (bench_layout, bench_gemm, bench_mha, bench_moe, bench_gemm_rs):

@@ -1,16 +1,19 @@
 """Distributed execution demo on 8 host-platform devices: Axe layouts
 drive shardings, collective inference, and the fused GEMM+ReduceScatter.
 
-This script re-execs itself with XLA_FLAGS so the parent environment
-keeps a single device.
+The fake mesh is CPU-only: the script re-execs itself, before touching
+JAX, with ``JAX_PLATFORMS=cpu`` and 8 host devices, so it never holds or
+waits for a chip. Everything it prints is a CPU result.
 
 Run:  PYTHONPATH=src python examples/distributed_demo.py
 """
 import os
 import sys
 
-if os.environ.get("XLA_FLAGS", "") == "":
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+_FLAGS = "--xla_force_host_platform_device_count=8"
+if os.environ.get("JAX_PLATFORMS") != "cpu" or os.environ.get("XLA_FLAGS") != _FLAGS:
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    os.environ["XLA_FLAGS"] = _FLAGS
     os.execv(sys.executable, [sys.executable] + sys.argv)
 
 import jax
@@ -29,7 +32,7 @@ def main():
     mesh = compat.make_mesh((2, 4), ("data", "model"))
     ms = axe_rules.mesh_shape_of(mesh)
     space = PhysicalSpace.from_mesh_shape(ms)
-    print("mesh:", ms)
+    print(f"mesh: {ms} (backend {jax.default_backend()}: fake host devices)")
 
     # --- Axe layout -> sharding for a weight matrix --------------------
     spec = DTensorSpec.from_pspec((1024, 512), (None, "model"), ms)

@@ -23,6 +23,7 @@ from repro.axe.spec import AxeSpec, PhysicalSpace, SpecError
 from repro.axe.program import (
     PROGRAMS,
     Epilogue,
+    KernelFallbackWarning,
     Program,
     ProgramError,
     StageContext,
@@ -139,6 +140,7 @@ __all__ = [
     "PhysicalSpace",
     "PlanEntry",
     "Program",
+    "KernelFallbackWarning",
     "ProgramError",
     "PropagationError",
     "Redistribution",

@@ -954,7 +954,6 @@ class Executable:
         )
 
     def _sharded_fn(self):
-        from repro import compat
         from repro.axe import lower as axe_lower
 
         names = self.activation_names + self.param_names
@@ -972,7 +971,7 @@ class Executable:
             axe_lower.to_pspec(self.plan.env[n]) for n in names
         ) + tuple(jax.sharding.PartitionSpec() for _ in self.aux_names)
         outs = tuple(axe_lower.to_pspec(self._out_specs[o]) for o in self.outputs)
-        return compat.shard_map(
+        return jax.shard_map(
             self._body, mesh=self.mesh, in_specs=in_pspecs,
             out_specs=outs[0] if len(outs) == 1 else outs, check_vma=False,
         )

@@ -30,7 +30,7 @@ import math
 from typing import Dict, Iterator, Optional, Sequence, Tuple
 
 from repro.axe.spec import DEFAULT_DEVICE_CLASS as DEFAULT_CLASS
-from repro.launch import mesh as meshmod
+from repro.launch import roofline
 
 HOST_CLASS = "host"
 
@@ -106,17 +106,19 @@ class ClassTable:
 
 
 def default_class_table() -> ClassTable:
-    """``accel`` is exactly the v5e roofline ``launch.mesh`` declares, so
-    a homogeneous space prices bit-identically to the pre-hetero model;
-    ``host`` is a no-flops CPU-memory tier behind a PCIe-class link."""
+    """``accel`` is exactly the peak-table row of the target chip
+    (``launch.roofline.TARGET_KIND``), so a homogeneous space prices
+    bit-identically to the pre-hetero model; ``host`` is a no-flops
+    CPU-memory tier behind a PCIe-class link."""
+    pk = roofline.device_peaks(roofline.TARGET_KIND)
     return ClassTable(
         classes=(
             DeviceClass(
                 DEFAULT_CLASS,
-                peak_flops=meshmod.PEAK_FLOPS_BF16,
-                mem_bw=meshmod.HBM_BW,
-                link_bw=meshmod.ICI_BW_PER_LINK * meshmod.ICI_LINKS,
-                capacity=float(meshmod.HBM_BYTES),
+                peak_flops=pk.flops_bf16,
+                mem_bw=pk.hbm_bw,
+                link_bw=pk.ici_bw_per_link * pk.ici_links,
+                capacity=float(pk.hbm_bytes),
             ),
             DeviceClass(
                 HOST_CLASS,

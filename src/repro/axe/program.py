@@ -60,6 +60,13 @@ class ProgramError(StageError):
     pass
 
 
+class KernelFallbackWarning(UserWarning):
+    """A compiled (non-interpret) dispatch that resolved to a kernel ran
+    its XLA body instead, because the kernel's tile was infeasible for
+    these shapes. Raised to an error where a run must prove it executed
+    the kernels (``chip_smoke.py``)."""
+
+
 #: process-wide registry: program name → Program (latest definition wins,
 #: so module reloads in tests do not error)
 PROGRAMS: Dict[str, "Program"] = {}
@@ -202,21 +209,31 @@ class StageContext:
 
     # -- lowering helpers -----------------------------------------------
     def pallas_call(self, body, *, grid, in_specs, out_specs, out_shape,
-                    scratch_shapes=None, dimension_semantics=None):
+                    scratch_shapes=None, dimension_semantics=None,
+                    num_scalar_prefetch: int = 0):
         """``pl.pallas_call`` with this invocation's interpret flag and
-        the compat TPU compiler params applied."""
+        the TPU compiler params applied. ``num_scalar_prefetch=n`` passes
+        the first ``n`` operands through SMEM (TPU scalar prefetch): the
+        body and every index map receive them as leading refs."""
         from jax.experimental import pallas as pl
+        import jax.experimental.pallas.tpu as pltpu
 
-        from repro import compat
-
-        kwargs = dict(
-            grid=grid, in_specs=list(in_specs), out_specs=out_specs,
-            out_shape=out_shape, interpret=self.interpret,
-        )
-        if scratch_shapes:
-            kwargs["scratch_shapes"] = list(scratch_shapes)
+        # the stage key names the kernel, so HLO and profiler traces can
+        # find it (``flash_attention_decode``, ``matmul_tile``, ...)
+        kwargs = dict(out_shape=out_shape, interpret=self.interpret,
+                      name=self.op.replace("/", "_"))
+        if num_scalar_prefetch:
+            kwargs["grid_spec"] = pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=num_scalar_prefetch, grid=grid,
+                in_specs=list(in_specs), out_specs=out_specs,
+                scratch_shapes=list(scratch_shapes or ()),
+            )
+        else:
+            kwargs.update(grid=grid, in_specs=list(in_specs), out_specs=out_specs)
+            if scratch_shapes:
+                kwargs["scratch_shapes"] = list(scratch_shapes)
         if dimension_semantics is not None:
-            kwargs["compiler_params"] = compat.tpu_compiler_params(
+            kwargs["compiler_params"] = pltpu.CompilerParams(
                 dimension_semantics=dimension_semantics
             )
         return pl.pallas_call(body, **kwargs)
@@ -434,7 +451,6 @@ class Program:
         (``axe.lower.to_pspec``), and the specs are forwarded to the
         program so MESH stages can draw their collective plans from
         them."""
-        from repro import compat
         from repro.axe import lower
 
         arg_specs = tuple(arg_specs)
@@ -444,7 +460,7 @@ class Program:
         def body(*arrays):
             return self(*arrays, arg_specs=arg_specs, **call_kw)
 
-        return compat.shard_map(
+        return jax.shard_map(
             body, mesh=mesh, in_specs=in_pspecs, out_specs=out_pspec,
             check_vma=False,
         )

@@ -1,68 +1,19 @@
-"""Thin jax-version compatibility layer.
+"""Mesh construction with Auto axis types.
 
-The repo targets recent jax, but the pinned container ships an older
-release where a few names moved. Everything that is version-sensitive
-funnels through here so the rest of the tree can use one spelling:
-
-* ``shard_map``            — ``jax.shard_map`` (new) vs
-                             ``jax.experimental.shard_map.shard_map``
-* ``make_mesh``            — ``jax.make_mesh`` with ``axis_types`` only
-                             when the running jax supports it
-* ``tpu_compiler_params``  — ``pltpu.CompilerParams`` (new name) vs
-                             ``pltpu.TPUCompilerParams``
+``jax.make_mesh`` defaults to Explicit axis types; every mesh in this
+repo is placed by ``shard_map``/``NamedSharding`` from solved layouts and
+expects Auto (GSPMD-propagated) axes, so construction funnels through
+:func:`make_mesh`.
 """
 from __future__ import annotations
 
-import inspect
-from typing import Sequence, Tuple
+from typing import Sequence
 
 import jax
-
-try:  # jax >= 0.4.35-ish
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
-
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map  # type: ignore[no-redef]
-
-_SHARD_MAP_PARAMS = frozenset(inspect.signature(_shard_map).parameters)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """``shard_map`` under either replication-check kwarg spelling
-    (``check_vma`` in new jax, ``check_rep`` before)."""
-    kwargs = {}
-    if check_vma is not None:
-        key = "check_vma" if "check_vma" in _SHARD_MAP_PARAMS else "check_rep"
-        kwargs[key] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-_MAKE_MESH_HAS_AXIS_TYPES = "axis_types" in inspect.signature(jax.make_mesh).parameters
+from jax.sharding import AxisType
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    shape = tuple(shape)
+    """``jax.make_mesh`` with Auto axis types."""
     axes = tuple(axes)
-    if _MAKE_MESH_HAS_AXIS_TYPES and AxisType is not None:
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def axis_size(axis_name: str) -> int:
-    """Static mapped-axis size (``jax.lax.axis_size`` where available;
-    ``psum(1, axis)`` constant-folds to the same int on older jax)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def tpu_compiler_params(*, dimension_semantics: Tuple[str, ...]):
-    """Mosaic compiler-params object under either of its two names."""
-    import jax.experimental.pallas.tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(pltpu, "TPUCompilerParams")
-    return cls(dimension_semantics=dimension_semantics)
+    return jax.make_mesh(tuple(shape), axes, axis_types=(AxisType.Auto,) * len(axes))

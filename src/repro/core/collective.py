@@ -225,9 +225,8 @@ def ring_all_gather(x: jax.Array, axis: str, dim: int) -> jax.Array:
     neighbor exchanges the latency-hiding scheduler can interleave with
     compute issued after it — the async form ``max(comm, compute)``
     charging assumes (docs/overlap.md)."""
-    from repro import compat
 
-    p = compat.axis_size(axis)
+    p = jax.lax.axis_size(axis)
     if p == 1:
         return x
     idx = jax.lax.axis_index(axis)
@@ -258,10 +257,9 @@ def lower_step(x: jax.Array, step: Step, *, overlap: bool = False) -> jax.Array:
             x, step.axis, split_axis=step.dst_dim, concat_axis=step.src_dim, tiled=True
         )
     if isinstance(step, DynamicSlice):
-        from repro import compat
 
         idx = jax.lax.axis_index(step.axis)
-        size = compat.axis_size(step.axis)  # jax.lax.axis_size is new-jax-only
+        size = jax.lax.axis_size(step.axis)
         chunk = x.shape[step.dim] // size
         return jax.lax.dynamic_slice_in_dim(x, idx * chunk, chunk, axis=step.dim)
     if isinstance(step, Transfer):

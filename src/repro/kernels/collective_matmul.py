@@ -27,7 +27,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.axe.program import program
 from repro.core.scopes import Scope
 
@@ -58,7 +57,7 @@ def _axis_of(kw, arg_specs) -> str:
 
 def _cm_key(args, kw, arg_specs=()):
     a, b = args[0], args[1]
-    p = compat.axis_size(_axis_of(kw, arg_specs))
+    p = jax.lax.axis_size(_axis_of(kw, arg_specs))
     return {
         "shapes": (tuple(a.shape), tuple(b.shape), (p,)),
         "dtypes": (a.dtype, b.dtype),
@@ -115,7 +114,7 @@ def _kshard(ctx, a, b, *, axis_name: str | None = None, out_dtype=None):
         ctx.arg_specs[0] if ctx.arg_specs else None
     )
     out_dtype = out_dtype or a.dtype
-    p = compat.axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
 
     if ctx.impl != "ring" or p == 1:
         partial = ctx.run("partial", a, b)

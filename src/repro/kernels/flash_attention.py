@@ -173,7 +173,7 @@ def _attend(ctx, q, k, v, *, causal: bool = False, window: int | None = None,
 @flash_attention_program.stage("decode_mac", scope=Scope.BLOCK)
 def _decode_mac(
     ctx,
-    q_ref, k_ref, v_ref, pos_ref, o_ref,
+    pos_ref, q_ref, k_ref, v_ref, o_ref,
     acc_ref, m_ref, l_ref,
     *,
     kv_steps: int,
@@ -194,10 +194,11 @@ def _decode_mac(
     k = k_ref[0].astype(jnp.float32)  # [bkv, d]
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
 
-    # cache validity from the per-slot position: linear caches attend
-    # to k_pos <= pos; a ring buffer that has wrapped (pos + 1 >= W) is
-    # entirely live — the same predicate the reference decode applies
-    pos_b = pos_ref[0, 0]
+    # cache validity from the per-slot position (an SMEM scalar, one per
+    # batch*kv-head row): linear caches attend to k_pos <= pos; a ring
+    # buffer that has wrapped (pos + 1 >= W) is entirely live — the same
+    # predicate the reference decode applies
+    pos_b = pos_ref[pl.program_id(0)]
     k_pos = kj * block_kv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     valid = k_pos <= pos_b
     if ring:
@@ -243,22 +244,23 @@ def _decode(ctx, q, k, v, pos, *, ring: bool = False, scale: float | None = None
             qr = q.reshape(bh, g, d)
             kr = k.reshape(bh, w, d)
             vr = v.reshape(bh, w, d)
-            pr = jnp.repeat(pos.astype(jnp.int32), kvh)[:, None]
+            # positions ride in SMEM as scalar prefetch: a (1, 1) VMEM
+            # block would break the TPU's (8, 128) block-shape rule
+            pr = jnp.repeat(pos.astype(jnp.int32), kvh)
 
             q_low = block_lowering((bh, g, d), (1, g, d), q.dtype,
-                                   index_map=lambda bhi, kj: (bhi, 0, 0),
+                                   index_map=lambda bhi, kj, _p: (bhi, 0, 0),
                                    op="flash_attention.decode.Q")
             k_low = block_lowering((bh, w, d), (1, block_kv, d), k.dtype,
-                                   index_map=lambda bhi, kj: (bhi, kj, 0),
+                                   index_map=lambda bhi, kj, _p: (bhi, kj, 0),
                                    op="flash_attention.decode.K")
             v_low = block_lowering((bh, w, d), (1, block_kv, d), v.dtype,
-                                   index_map=lambda bhi, kj: (bhi, kj, 0),
+                                   index_map=lambda bhi, kj, _p: (bhi, kj, 0),
                                    op="flash_attention.decode.V")
             o_low = block_lowering((bh, g, d), (1, g, d), q.dtype,
-                                   index_map=lambda bhi, kj: (bhi, 0, 0),
+                                   index_map=lambda bhi, kj, _p: (bhi, 0, 0),
                                    op="flash_attention.decode.O")
             kv_steps = k_low.grid[1]
-            pos_spec = pl.BlockSpec((1, 1), lambda bhi, kj: (bhi, 0))
 
             body = functools.partial(
                 ctx.run, "decode_mac",
@@ -268,7 +270,7 @@ def _decode(ctx, q, k, v, pos, *, ring: bool = False, scale: float | None = None
             out = ctx.pallas_call(
                 lambda *refs: body(*refs),
                 grid=(bh, kv_steps),
-                in_specs=[q_low.spec, k_low.spec, v_low.spec, pos_spec],
+                in_specs=[q_low.spec, k_low.spec, v_low.spec],
                 out_specs=o_low.spec,
                 out_shape=jax.ShapeDtypeStruct((bh, g, d), q.dtype),
                 scratch_shapes=[
@@ -277,7 +279,8 @@ def _decode(ctx, q, k, v, pos, *, ring: bool = False, scale: float | None = None
                     pltpu.VMEM((g, 1), jnp.float32),
                 ],
                 dimension_semantics=("parallel", "arbitrary"),
-            )(qr, kr, vr, pr)
+                num_scalar_prefetch=1,
+            )(pr, qr, kr, vr)
             return out.reshape(b, kvh, g, d)
 
         return launch

@@ -20,13 +20,15 @@ Dispatch by execution scope: MESH/BLOCK → ``dot``, DEVICE/GRID →
 """
 from __future__ import annotations
 
+import warnings
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 from repro.axe.lower import block_lowering
-from repro.axe.program import program
+from repro.axe.program import KernelFallbackWarning, program
 from repro.core.blockspec import TilingError, check_tiling
 from repro.core.scopes import Scope
 
@@ -81,6 +83,17 @@ def _tile(ctx, a, b, *, out_dtype=None):
     out_dtype = out_dtype or a.dtype
     epi = ctx.epilogue
 
+    def fallback(exc):
+        """An infeasible tile: the caller's pinned kernel fails loudly;
+        a resolved one runs the XLA dot, and says so when compiled."""
+        if ctx.pinned:
+            raise exc  # the unified error path
+        if not ctx.interpret:
+            warnings.warn(KernelFallbackWarning(
+                f"matmul/tile {a.shape}x{b.shape}: {exc}; running the XLA dot"
+            ), stacklevel=3)
+        return finish(ctx.run("dot", a, b, out_dtype=out_dtype))
+
     def finish(out):
         """Functional epilogue application — the fallback whenever the
         chain cannot run inside the Pallas launch (XLA variant, non-2D
@@ -108,10 +121,8 @@ def _tile(ctx, a, b, *, out_dtype=None):
         # fail fast on infeasible output tiles (same precheck the legacy
         # dispatch made); A/B tilings are re-validated inside the launch
         check_tiling((m, n), (bm, bn), a.dtype, op="matmul/tile")
-    except TilingError:
-        if ctx.pinned:
-            raise  # caller pinned the kernel: the unified error path
-        return finish(ctx.run("dot", a, b, out_dtype=out_dtype))
+    except TilingError as exc:
+        return fallback(exc)
 
     n_extras = len(epi.args) if inline else 0
 
@@ -152,10 +163,8 @@ def _tile(ctx, a, b, *, out_dtype=None):
     key = (bm, bn, bk, str(out_dtype), epi.tag if inline else None, n_extras)
     try:
         out = ctx.jit(key, make)(a, b, *(tuple(epi.args) if inline else ()))
-    except TilingError:
-        if ctx.pinned:
-            raise
-        return finish(ctx.run("dot", a, b, out_dtype=out_dtype))
+    except TilingError as exc:
+        return fallback(exc)
     return out if inline else finish(out)
 
 

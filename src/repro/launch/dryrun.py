@@ -1,6 +1,8 @@
 import os
-# respect a caller-set XLA_FLAGS (the CI execute-smoke leg pins 8 host
+# a CPU-only tool: its meshes are fake host devices, never a chip.
+# Respect a caller-set XLA_FLAGS (the CI execute-smoke leg pins 8 host
 # devices); default to the 512-device deviceless-lowering geometry
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell
@@ -517,6 +519,9 @@ def lower_cell(
         "mesh": "multi" if multi_pod else "single",
         "kind": shape.kind, "batch": shape.batch, "seq": shape.seq,
         "params": cfg.param_count(), "active_params": cfg.active_param_count(),
+        # compiled on CPU host devices; only the roofline is priced for
+        # the target chip (nothing here ran on it)
+        "backend": "cpu", "target_kind": rl.TARGET_KIND,
         "options": {"fsdp": fsdp, "zero1": zero1, "microbatches": microbatches,
                     "compress_pod_grads": compress_pod_grads, "remat": remat},
     }
@@ -623,7 +628,7 @@ def lower_cell(
     try:
         terms = rl.derive_terms(
             hlo_text=hlo, n_chips=n_chips,
-            model_flops_total=mf, pod_axis=multi_pod,
+            model_flops_total=mf, kind=rl.TARGET_KIND, pod_axis=multi_pod,
         )
         record["roofline"] = terms.to_dict()
     except Exception as e:
@@ -850,7 +855,8 @@ def main():
                    "traceback": traceback.format_exc()[-2000:]}
         line = json.dumps(rec)
         print(line if rec["status"] != "ok" else
-              f"OK {arch} {shape} {mesh} lower={rec.get('lower_s')}s "
+              f"OK {arch} {shape} {mesh} [cpu host devices; roofline "
+              f"priced for {rec.get('target_kind')}] lower={rec.get('lower_s')}s "
               f"compile={rec.get('compile_s')}s "
               f"bottleneck={rec.get('roofline', {}).get('bottleneck')}")
         if rec["status"] == "ok":

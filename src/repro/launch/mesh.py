@@ -29,12 +29,3 @@ def make_local_mesh(model: int = 1) -> Mesh:
     n = len(jax.devices())
     assert n % model == 0
     return make_mesh((n // model, model), ("data", "model"))
-
-
-# v5e hardware constants used by the roofline (per chip)
-PEAK_FLOPS_BF16 = 197e12      # FLOP/s
-HBM_BW = 819e9                # B/s
-ICI_BW_PER_LINK = 50e9        # B/s per link
-ICI_LINKS = 4                 # 2D torus: 4 links/chip (v5e)
-DCI_BW = 25e9                 # B/s cross-pod (data-center interconnect), est.
-HBM_BYTES = 16 * 1024**3      # 16 GiB

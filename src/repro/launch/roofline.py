@@ -15,7 +15,6 @@ import dataclasses
 import re
 from typing import Dict, Tuple
 
-from repro.launch import mesh as meshmod
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -71,28 +70,63 @@ def collective_bytes_of_hlo(hlo_text: str) -> Dict[str, int]:
     return {"bytes": out, "counts": counts}
 
 
-# Per-backend (peak FLOP/s, memory bandwidth B/s) for the schedule
-# planner's analytic ranking. TPU numbers are the v5e chip constants;
-# cpu/gpu are deliberately rough — the planner only needs relative
-# ordering of candidate schedules, and the autotuner's measurements
-# override the model wherever it matters.
-BACKEND_PEAKS = {
-    "tpu": (meshmod.PEAK_FLOPS_BF16, meshmod.HBM_BW),
-    "gpu": (100e12, 1000e9),
-    "cpu": (200e9, 50e9),
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one accelerator kind."""
+
+    flops_bf16: float      # FLOP/s
+    hbm_bw: float          # B/s
+    hbm_bytes: float       # device memory
+    ici_bw_per_link: float  # B/s per chip-to-chip link
+    ici_links: int
+
+
+#: Per-chip peaks keyed by ``jax.Device.device_kind``. Source: Google
+#: Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GiB HBM at
+#: 819 GB/s, 1,600 Gbit/s of ICI over 4 links (2D torus).
+DEVICE_PEAKS: Dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(
+        flops_bf16=197e12, hbm_bw=819e9, hbm_bytes=16 * 1024**3,
+        ici_bw_per_link=50e9, ici_links=4,
+    ),
 }
+
+#: the chip the solver's cost model and the deviceless dry-run price for
+TARGET_KIND = "TPU v5 lite"
+
+
+def device_peaks(kind: str) -> DevicePeaks:
+    """The peak table row for ``kind``; a kind not in the table is an
+    error, never a default."""
+    try:
+        return DEVICE_PEAKS[kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak rates for device kind {kind!r} (known: "
+            f"{sorted(DEVICE_PEAKS)}); add its published peaks to "
+            f"launch.roofline.DEVICE_PEAKS"
+        ) from None
+
+
+#: host-CPU (peak FLOP/s, memory B/s) for the schedule planner's
+#: analytic ranking off the chip, where kernels run in interpret mode.
+#: Rough on purpose: the planner only needs relative order there, and
+#: no device metric is ever derived from it.
+CPU_PEAKS = (200e9, 50e9)
 
 
 def _peaks(backend: str) -> Tuple[float, float]:
-    """Per-backend (peak_flops, mem_bw). The accelerator backend reads
-    the *active* device-class table (repro.axe.hetero) — its default
-    table is exactly the v5e constants above, so homogeneous costing is
-    unchanged; tests flip the table to flip relative costs."""
+    """(peak_flops, mem_bw) the planner ranks with. The accelerator
+    reads the *active* device-class table (repro.axe.hetero), whose
+    default is the :data:`TARGET_KIND` row; tests flip the table to flip
+    relative costs. Any backend other than ``tpu``/``cpu`` raises."""
     if backend == "tpu":
         from repro.axe import hetero
 
         return hetero.default_peaks()
-    return BACKEND_PEAKS.get(backend, BACKEND_PEAKS["cpu"])
+    if backend == "cpu":
+        return CPU_PEAKS
+    raise ValueError(f"no planner peaks for backend {backend!r}")
 
 
 def _link_bw() -> float:
@@ -156,16 +190,19 @@ def derive_terms(
     hlo_text: str,
     n_chips: int,
     model_flops_total: float,
+    kind: str,
     pod_axis: bool = False,
 ) -> RooflineTerms:
     """Three-term roofline from the optimized per-device HLO, using the
-    loop-aware analyzer (XLA's cost_analysis counts scan bodies once)."""
+    loop-aware analyzer (XLA's cost_analysis counts scan bodies once),
+    priced with the peaks of device ``kind``."""
     from repro.launch import hlo_cost
 
     c = hlo_cost.analyze(hlo_text, total_devices=n_chips)
 
-    peak_flops, mem_bw = _peaks("tpu")
-    ici_bw = _link_bw()
+    pk = device_peaks(kind)
+    peak_flops, mem_bw = pk.flops_bf16, pk.hbm_bw
+    ici_bw = pk.ici_bw_per_link * pk.ici_links
     compute_s = c.flops / peak_flops
     memory_s = c.bytes / mem_bw
     collective_s = c.comm_bytes / ici_bw

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import ARCH_IDS, get_config, smoke_variant
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model_zoo import build_model
 from repro.serve.engine import ServeEngine
 
@@ -29,6 +30,7 @@ def main():
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -18,6 +18,7 @@ from repro.axe.spec import PhysicalSpace
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import ARCH_IDS, get_config, smoke_variant
 from repro.data.pipeline import SyntheticLMData
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model_zoo import build_model
 from repro.optim.adamw import AdamW
 from repro.optim.schedule import warmup_cosine
@@ -69,6 +70,7 @@ def main():
                          "mesh axis (must divide the device count; "
                          "degrades to 1 — a no-op — when it does not)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

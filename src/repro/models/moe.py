@@ -147,9 +147,8 @@ def moe_apply_expert_parallel(p: Params, x: jax.Array, cfg, mesh) -> jax.Array:
         y = _local_combine(back, meta, t, d)
         return y.reshape(b_loc, s_loc, d).astype(xl.dtype)
 
-    from repro import compat
 
-    return compat.shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

@@ -128,15 +128,17 @@ class ServeEngine:
         shardings = axe_rules.sharding_tree(specs, self.mesh)
         return jax.device_put(params, shardings)
 
-    def _place_cache(self, cache):
+    def _cache_shardings(self, cache):
         from repro.axe import rules as axe_rules
 
         with self._dedup_warnings():
             specs = axe_rules.cache_specs(
                 cache, self._space(), plan=self.layout_plan
             )
-        shardings = axe_rules.sharding_tree(specs, self.mesh)
-        return jax.device_put(cache, shardings)
+        return axe_rules.sharding_tree(specs, self.mesh)
+
+    def _place_cache(self, cache):
+        return jax.device_put(cache, self._cache_shardings(cache))
 
     def _scheduled(self, fn):
         """Hold the forced-schedule context across calls so jit tracing
@@ -220,16 +222,45 @@ class ServeEngine:
         """One compiled decode step: ``tok [B]`` current tokens,
         ``pos [B]`` per-slot positions (requests in one batch may sit at
         different depths), legacy-layout ``cache`` pytree in/out.
-        Returns ``(logits [B, V], new_cache)``."""
+        Returns ``(logits [B, V], new_cache)``.
+
+        ``cache`` is donated: the caller must drop its reference and use
+        the returned one."""
+        b = int(tok.shape[0])
+        return self.decode_fn(batch=b)(self.params, cache, tok, pos)
+
+    def decode_fn(self, *, batch: Optional[int] = None):
+        """The jitted serving step ``(params, cache, tok, pos) ->
+        (logits, new_cache)`` around :meth:`compiled_decode`. Binding the
+        stacked params and cache onto the graph's per-layer inputs
+        (``decode_inputs``/``decode_cache``) happens inside the jit, so a
+        step never materializes a second copy of the weights, and the
+        cache argument is donated so the new cache reuses its buffers.
+        On a mesh the new cache keeps the placed cache's shardings, so the
+        next step reuses this executable and the donation aliases."""
         from repro.axe.compile import decode_cache, decode_inputs
 
-        b = int(tok.shape[0])
-        exe = self.compiled_decode(batch=b)
-        run = self._scheduled(exe)
-        inputs = decode_inputs(exe.graph, self.api.cfg, self.params, cache)
-        outs = run(inputs, tok, pos)
-        logits = dict(zip(exe.graph.outputs(), outs))["logits"]
-        return logits, decode_cache(exe.graph, self.api.cfg, outs, cache)
+        key = ("decode_fn", batch or self.batch_size)
+        fn = self._compiled.get(key)
+        if fn is None:
+            exe = self.compiled_decode(batch=batch)
+            cfg = self.api.cfg
+
+            def step(params, cache, tok, pos):
+                outs = exe.apply(decode_inputs(exe.graph, cfg, params, cache),
+                                 tok, pos)
+                logits = dict(zip(exe.graph.outputs(), outs))["logits"]
+                new_cache = decode_cache(exe.graph, cfg, outs, cache)
+                if self.mesh is not None:
+                    new_cache = jax.lax.with_sharding_constraint(
+                        new_cache, self._cache_shardings(cache))
+                return logits, new_cache
+
+            fn = self._scheduled(jax.jit(step, donate_argnums=(1,)))
+            while len(self._compiled) >= self.MAX_COMPILED:
+                self._compiled.pop(next(iter(self._compiled)))
+            self._compiled[key] = fn
+        return fn
 
     def score(self, tokens: jax.Array) -> jax.Array:
         """Full-sequence logits [B, S, V] through the compiled graph —

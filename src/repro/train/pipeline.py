@@ -119,9 +119,8 @@ def pipeline_apply(
     replicated = axe_lower.to_pspec(
         AxeSpec.replicated(microbatches.shape, space)
     )
-    from repro import compat
 
-    return compat.shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec_params, replicated),

@@ -145,9 +145,8 @@ def test_apply_plan_single_device_mesh():
     def body(x):
         return coll.apply_plan(x, [coll.AllGather("model", 0)])
 
-    from repro import compat
 
-    y = compat.shard_map(
+    y = jax.shard_map(
         body, mesh=mesh, in_specs=P("model", None), out_specs=P(None, None),
         check_vma=False,
     )(x)

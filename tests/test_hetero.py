@@ -25,7 +25,9 @@ from repro.axe.propagate import OpNode, redistribute
 from repro.axe.solve import SolveError, solve
 from repro.axe.spec import AxeSpec, PhysicalSpace
 from repro.core.collective import AllGather, AllReduce, Transfer
-from repro.launch import mesh as meshmod
+from repro.launch import roofline
+
+V5E = roofline.device_peaks(roofline.TARGET_KIND)
 
 # ---------------------------------------------------------------------------
 # tables / parsing
@@ -36,14 +38,22 @@ def test_default_table_matches_accelerator_constants():
     # homogeneous costing must be bit-identical with or without the
     # hetero module in the loop: the default accel class IS the v5e
     # profile launch/roofline always used
-    assert hetero.default_peaks() == (meshmod.PEAK_FLOPS_BF16, meshmod.HBM_BW)
-    assert hetero.default_link_bw() == (
-        meshmod.ICI_BW_PER_LINK * meshmod.ICI_LINKS
-    )
+    assert hetero.default_peaks() == (V5E.flops_bf16, V5E.hbm_bw)
+    assert hetero.default_link_bw() == V5E.ici_bw_per_link * V5E.ici_links
     table = hetero.default_class_table()
     host = table.cls("host")
     assert host.peak_flops == 0.0 and not host.computes
     assert table.cls(table.default).computes
+
+
+def test_device_peaks_keyed_by_kind():
+    # the table is keyed by jax's device_kind; an unknown kind raises
+    # instead of pricing with another chip's (or the CPU's) peaks
+    assert V5E.flops_bf16 == 197e12 and V5E.hbm_bw == 819e9
+    with pytest.raises(ValueError, match="no peak rates"):
+        roofline.device_peaks("TPU v9 imaginary")
+    with pytest.raises(ValueError):
+        roofline.schedule_time(flops=1.0, mem_bytes=1.0, backend="gpu")
 
 
 def test_parse_classes_roundtrip_and_errors():
@@ -53,7 +63,7 @@ def test_parse_classes_roundtrip_and_errors():
     assert host.capacity == 1e6
     # unnamed classes keep their defaults; the default class stays accel
     assert t.default == "accel"
-    assert t.cls("accel").peak_flops == meshmod.PEAK_FLOPS_BF16
+    assert t.cls("accel").peak_flops == V5E.flops_bf16
     with pytest.raises(hetero.HeteroError):
         hetero.parse_classes("garbage")
     with pytest.raises(hetero.HeteroError):
@@ -148,8 +158,8 @@ def _capacity_table(host_link: float) -> hetero.ClassTable:
     """Accelerator capacity below the embedding table's 4096 B — the
     solver must shard or park it; the host link speed decides which."""
     return hetero.ClassTable(classes=(
-        hetero.DeviceClass("accel", meshmod.PEAK_FLOPS_BF16,
-                           meshmod.HBM_BW, 200e9, capacity=2048.0),
+        hetero.DeviceClass("accel", V5E.flops_bf16,
+                           V5E.hbm_bw, 200e9, capacity=2048.0),
         hetero.DeviceClass("host", 0.0, 100e9, host_link),
     ))
 

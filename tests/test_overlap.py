@@ -284,10 +284,10 @@ out = {}
 for dim, shape in ((0, (16, 5)), (1, (4, 24))):
     x = jax.random.normal(jax.random.PRNGKey(dim), shape, jnp.float32)
     spec = P("x") if dim == 0 else P(None, "x")
-    ring = compat.shard_map(lambda v: ring_all_gather(v, "x", dim),
+    ring = jax.shard_map(lambda v: ring_all_gather(v, "x", dim),
                             mesh=mesh, in_specs=(spec,), out_specs=P(),
                             check_vma=False)
-    ref = compat.shard_map(
+    ref = jax.shard_map(
         lambda v: jax.lax.all_gather(v, "x", axis=dim, tiled=True),
         mesh=mesh, in_specs=(spec,), out_specs=P(), check_vma=False)
     got, want = np.asarray(ring(x)), np.asarray(ref(x))

@@ -412,3 +412,51 @@ def test_decode_parity_8_devices():
     for arch, rec in out.items():
         assert rec["logits_maxdiff"] < 2e-4, (arch, rec)
         assert rec["cache_maxdiff"] < 2e-4, (arch, rec)
+
+
+_ENGINE_CHILD = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import json
+import jax, jax.numpy as jnp
+from repro import compat
+from repro.configs import get_config, smoke_variant
+from repro.models.model_zoo import build_model
+from repro.serve.engine import ServeEngine
+
+api = build_model(smoke_variant(get_config("qwen3-4b")))
+eng = ServeEngine(api, batch_size=4, max_seq=32,
+                  mesh=compat.make_mesh((1, 4), ("data", "model")))
+eng.load(api.init(jax.random.PRNGKey(0)))
+eng.generate(jnp.ones((4, 5), jnp.int32), 4)
+compiles = eng.decode_fn()._cache_size()
+placed = eng._place_cache(api.cache_init(4, 32))
+want = [s.sharding for s in jax.tree.leaves(placed)]
+_, cache = eng.decode_step(jnp.zeros(4, jnp.int32), placed,
+                           jnp.full((4,), 5, jnp.int32))
+got = [s.sharding for s in jax.tree.leaves(cache)]
+print("RESULT " + json.dumps({
+    "compiles": compiles,
+    "same_placement": all(g.is_equivalent_to(w, 5) for g, w in zip(got, want)),
+}))
+"""
+
+
+def test_sharded_engine_decode_keeps_cache_placement():
+    """On a mesh the serving step returns its cache with the placed
+    cache's shardings: every step of ``generate`` reuses one executable
+    (a replicated new cache would recompile the second step and hold
+    the whole cache on every device)."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(__file__), "..", "src")]
+        + env.get("PYTHONPATH", "").split(os.pathsep)
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", _ENGINE_CHILD], env=env,
+        capture_output=True, text=True, timeout=600,
+    )
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT ")][-1]
+    out = json.loads(line[len("RESULT "):])
+    assert out == {"compiles": 1, "same_placement": True}, out
