@@ -33,6 +33,7 @@ their forward passes from.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence, Tuple
@@ -64,6 +65,16 @@ from repro.core.scopes import Scope, scope
 
 class CompileError(ValueError):
     pass
+
+
+@contextlib.contextmanager
+def node_scope(node: OpNode):
+    """Name the device work of one graph op: every HLO op it lowers to
+    carries ``<kind>/<node name>/`` in its ``op_name`` (a profiler trace
+    reports it as the op's ``tf_op``). Metadata only: the compiled
+    program is otherwise unchanged."""
+    with jax.named_scope(node.kind), jax.named_scope(node.name):
+        yield
 
 
 # ---------------------------------------------------------------------------
@@ -777,7 +788,6 @@ class Executable:
         prefetched: Dict[Tuple[int, str], Any] = {}
         with scope(Scope.DEVICE):
             for ei, entry in enumerate(self.plan.entries):
-                node = entry.op
                 # issue the collectives scheduled to hide under THIS
                 # entry's compute (each feeds a later entry; its input
                 # is already final — see redist_overlappable)
@@ -788,68 +798,77 @@ class Executable:
                 # accelerators the ring form engages (same dispatch
                 # convention as the program stages' XLA variants).
                 for tgt, r in self._prefetch.get(ei, ()):
-                    prefetched[(tgt, r.operand)] = coll.apply_plan(
-                        env[r.operand], r.steps, overlap=not self.interpret
-                    )
+                    target = self.plan.entries[tgt].op
+                    with node_scope(target):
+                        prefetched[(tgt, r.operand)] = coll.apply_plan(
+                            env[r.operand], r.steps, overlap=not self.interpret
+                        )
                     self._issued.append(
-                        (self.plan.entries[tgt].op.name, r.operand,
+                        (target.name, r.operand,
                          tuple(type(s).__name__ for s in r.steps))
                     )
-                if node.kind == "finalize":
-                    x = env[node.out]
-                    for r in entry.redistributions:
-                        x = coll.apply_plan(x, r.steps)
-                        self._issued.append(
-                            (node.name, r.operand,
-                             tuple(type(s).__name__ for s in r.steps))
-                        )
-                    env[node.out] = x
-                    continue
-                vals = {nm: env[nm] for nm in node.inputs}
-                specs = {nm: self.plan.env[nm] for nm in node.inputs}
-                shape_steps = ()
-                internal: Dict[str, List] = {}
-                for r in entry.redistributions:
-                    if r.operand not in vals:
-                        # a fused chain intermediate (not a node input):
-                        # the fused runner applies it between segments
-                        internal.setdefault(r.operand, []).append(r)
-                    elif (ei, r.operand) in self._hoisted:
-                        # issued one entry early; consume the buffer
-                        # (already recorded in _issued at the issue slot)
-                        vals[r.operand] = prefetched.pop((ei, r.operand))
-                        specs[r.operand] = r.dst
-                        continue
-                    elif r.dst.shape == r.src.shape:
-                        vals[r.operand] = coll.apply_plan(vals[r.operand], r.steps)
-                        specs[r.operand] = r.dst
-                    else:
-                        # shape-changing exchange: the op backend
-                        # owns these steps (MoE dispatch/combine)
-                        shape_steps = r.steps
-                    if r.steps:
-                        self._issued.append(
-                            (node.name, r.operand,
-                             tuple(type(s).__name__ for s in r.steps))
-                        )
-                if epilogue_steps(node):
-                    out = self._run_fused(node, entry, vals, specs,
-                                          internal, aux, side, mesh_shape)
-                else:
-                    ins = [vals[nm] for nm in node.inputs]
-                    in_specs = [specs[nm] for nm in node.inputs]
-                    ctx = ExecCtx(node, entry, in_specs, aux, side, shape_steps,
-                                  mesh_shape, self.interpret)
-                    out = op_backend(node.kind)(ctx, *ins)
-                want = entry.out_spec.local_shape()
-                if tuple(out.shape) != tuple(want):
-                    raise CompileError(
-                        f"{node.name} [{node.kind}]: backend produced local "
-                        f"shape {tuple(out.shape)}, plan says {tuple(want)}"
-                    )
-                env[node.out] = out
+                with node_scope(entry.op):
+                    env[entry.op.out] = self._run_entry(
+                        ei, entry, env, prefetched, aux, side, mesh_shape)
         outs = tuple(env[o] for o in self.outputs)
         return outs[0] if len(outs) == 1 else outs
+
+    def _run_entry(self, ei, entry, env, prefetched, aux, side, mesh_shape):
+        """One plan entry: its redistributions, then its op's backend.
+        Returns the op's local output."""
+        node = entry.op
+        if node.kind == "finalize":
+            x = env[node.out]
+            for r in entry.redistributions:
+                x = coll.apply_plan(x, r.steps)
+                self._issued.append(
+                    (node.name, r.operand,
+                     tuple(type(s).__name__ for s in r.steps))
+                )
+            return x
+        vals = {nm: env[nm] for nm in node.inputs}
+        specs = {nm: self.plan.env[nm] for nm in node.inputs}
+        shape_steps = ()
+        internal: Dict[str, List] = {}
+        for r in entry.redistributions:
+            if r.operand not in vals:
+                # a fused chain intermediate (not a node input):
+                # the fused runner applies it between segments
+                internal.setdefault(r.operand, []).append(r)
+            elif (ei, r.operand) in self._hoisted:
+                # issued one entry early; consume the buffer
+                # (already recorded in _issued at the issue slot)
+                vals[r.operand] = prefetched.pop((ei, r.operand))
+                specs[r.operand] = r.dst
+                continue
+            elif r.dst.shape == r.src.shape:
+                vals[r.operand] = coll.apply_plan(vals[r.operand], r.steps)
+                specs[r.operand] = r.dst
+            else:
+                # shape-changing exchange: the op backend
+                # owns these steps (MoE dispatch/combine)
+                shape_steps = r.steps
+            if r.steps:
+                self._issued.append(
+                    (node.name, r.operand,
+                     tuple(type(s).__name__ for s in r.steps))
+                )
+        if epilogue_steps(node):
+            out = self._run_fused(node, entry, vals, specs,
+                                  internal, aux, side, mesh_shape)
+        else:
+            ins = [vals[nm] for nm in node.inputs]
+            in_specs = [specs[nm] for nm in node.inputs]
+            ctx = ExecCtx(node, entry, in_specs, aux, side, shape_steps,
+                          mesh_shape, self.interpret)
+            out = op_backend(node.kind)(ctx, *ins)
+        want = entry.out_spec.local_shape()
+        if tuple(out.shape) != tuple(want):
+            raise CompileError(
+                f"{node.name} [{node.kind}]: backend produced local "
+                f"shape {tuple(out.shape)}, plan says {tuple(want)}"
+            )
+        return out
 
     # -- fused-epilogue execution (axe.passes, docs/passes.md) -----------
     def _run_fused(self, node, entry, vals, specs, internal, aux, side,

@@ -25,6 +25,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 class PagePoolError(RuntimeError):
@@ -256,18 +257,21 @@ class ContinuousBatcher:
 
         # batch-1 prefill through the legacy model API (disaggregated
         # from the batched compiled decode)
-        one = eng.api.cache_init(1, eng.max_seq)
-        logits, one = eng._prefill(eng.params, {"tokens": prompt[None, :]}, one)
-        tok = int(self._sample_one(req.uid, len(prompt) - 1, logits[0, -1]))
+        with TraceAnnotation("prefill", uid=req.uid, prompt_len=len(prompt)):
+            one = eng.api.cache_init(1, eng.max_seq)
+            logits, one = eng._prefill(eng.params, {"tokens": prompt[None, :]}, one)
+        with TraceAnnotation("first_token", uid=req.uid):
+            tok = int(self._sample_one(req.uid, len(prompt) - 1, logits[0, -1]))
 
         # write the prefilled cache into this slot (leaves are
         # [n_super, B, ...]: batch is axis 1)
-        self.cache = jax.tree.map(
-            lambda big, new: jax.lax.dynamic_update_slice_in_dim(
-                big, new.astype(big.dtype), slot.index, axis=1
-            ),
-            self.cache, one,
-        )
+        with TraceAnnotation("slot_write", uid=req.uid):
+            self.cache = jax.tree.map(
+                lambda big, new: jax.lax.dynamic_update_slice_in_dim(
+                    big, new.astype(big.dtype), slot.index, axis=1
+                ),
+                self.cache, one,
+            )
         slot.uid = req.uid
         slot.pos = len(prompt)
         slot.remaining = req.max_new_tokens - 1
@@ -403,7 +407,19 @@ class ContinuousBatcher:
     def step(self) -> bool:
         """One scheduler tick: admit arrivals into free slots, run one
         batched compiled decode over the active slots, retire finished
-        requests. Returns False when nothing is left to do."""
+        requests. Returns False when nothing is left to do.
+
+        The tick and its host phases are profiler spans
+        (docs/serving.md, "Tracing a server"): ``step`` (args ``step``,
+        ``live``, ``queued``) holds ``admit`` (arg ``uid``; children
+        ``prefill``, ``first_token``, ``slot_write``), ``inputs``,
+        ``decode`` and ``sample``. A span waits for nothing on the
+        device; with no profiler running it records nothing."""
+        with TraceAnnotation("step", step=self.step_count, live=self.active,
+                             queued=len(self.queue) + len(self.pending)):
+            return self._step()
+
+    def _step(self) -> bool:
         # arrivals whose time has come
         while self.pending and self.pending[0].arrival <= self.step_count:
             self.queue.append(self.pending.pop(0))
@@ -444,7 +460,8 @@ class ContinuousBatcher:
                     break
                 slot = self._free_slot()
             self.queue.pop(0)
-            self._admit(req, slot)
+            with TraceAnnotation("admit", uid=req.uid):
+                self._admit(req, slot)
 
         live = [s for s in self.slots if s.uid is not None]
         if not live:
@@ -452,14 +469,17 @@ class ContinuousBatcher:
             self.step_count += 1
             return not done
 
-        tok = jnp.asarray([s.last_tok for s in self.slots], jnp.int32)
-        pos = jnp.asarray([s.pos for s in self.slots], jnp.int32)
-        logits, self.cache = self.engine.decode_step(tok, self.cache, pos)
-        sampled = self._sample_batch(
-            np.asarray([s.uid if s.uid is not None else 0 for s in self.slots]),
-            np.asarray([s.pos for s in self.slots]),
-            logits,
-        )
+        with TraceAnnotation("inputs"):
+            tok = jnp.asarray([s.last_tok for s in self.slots], jnp.int32)
+            pos = jnp.asarray([s.pos for s in self.slots], jnp.int32)
+        with TraceAnnotation("decode"):
+            logits, self.cache = self.engine.decode_step(tok, self.cache, pos)
+        with TraceAnnotation("sample"):
+            sampled = self._sample_batch(
+                np.asarray([s.uid if s.uid is not None else 0 for s in self.slots]),
+                np.asarray([s.pos for s in self.slots]),
+                logits,
+            )
         self.step_count += 1
         for s in live:
             t = int(sampled[s.index])

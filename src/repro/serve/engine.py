@@ -236,6 +236,10 @@ class ServeEngine:
         (``decode_inputs``/``decode_cache``) happens inside the jit, so a
         step never materializes a second copy of the weights, and the
         cache argument is donated so the new cache reuses its buffers.
+        Its device ops are named by scope (docs/serving.md, "Tracing a
+        server"): ``bind`` (weight and cache slices, the tied head's
+        transpose), ``restack`` (the new cache), and each graph op's
+        ``<kind>/<node>`` from the executable.
         On a mesh the new cache keeps the placed cache's shardings, so the
         next step reuses this executable and the donation aliases."""
         from repro.axe.compile import decode_cache, decode_inputs
@@ -247,13 +251,15 @@ class ServeEngine:
             cfg = self.api.cfg
 
             def step(params, cache, tok, pos):
-                outs = exe.apply(decode_inputs(exe.graph, cfg, params, cache),
-                                 tok, pos)
+                with jax.named_scope("bind"):
+                    inputs = decode_inputs(exe.graph, cfg, params, cache)
+                outs = exe.apply(inputs, tok, pos)
                 logits = dict(zip(exe.graph.outputs(), outs))["logits"]
-                new_cache = decode_cache(exe.graph, cfg, outs, cache)
-                if self.mesh is not None:
-                    new_cache = jax.lax.with_sharding_constraint(
-                        new_cache, self._cache_shardings(cache))
+                with jax.named_scope("restack"):
+                    new_cache = decode_cache(exe.graph, cfg, outs, cache)
+                    if self.mesh is not None:
+                        new_cache = jax.lax.with_sharding_constraint(
+                            new_cache, self._cache_shardings(cache))
                 return logits, new_cache
 
             fn = self._scheduled(jax.jit(step, donate_argnums=(1,)))
