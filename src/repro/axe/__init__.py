@@ -97,9 +97,11 @@ from repro.axe.passes import (
     fuse_graph,
 )
 from repro.axe.compile import (
+    BindReport,
     CompileError,
     Executable,
     LoweredOp,
+    bind_report,
     compile,
     compiled_loss_fn,
     decode_cache,
@@ -116,6 +118,7 @@ __all__ = [
     "AxeSpec",
     "BlockLowering",
     "ClassTable",
+    "BindReport",
     "CompileError",
     "CotuneIteration",
     "CotuneResult",
@@ -153,6 +156,7 @@ __all__ = [
     "StageError",
     "TensorMeta",
     "block_lowering",
+    "bind_report",
     "cache_window",
     "class_table",
     "compile",

@@ -1337,12 +1337,73 @@ def model_executable(
     return exe
 
 
+def _matmul_weights(graph: GraphSpec) -> Dict[str, bool]:
+    """The params that are the weight operand (B) of a matmul, each with
+    whether that is the only way any op reads it."""
+    uses: Dict[str, set] = {}
+    for node in graph.nodes:
+        for k, nm in enumerate(node.inputs):
+            uses.setdefault(nm, set()).add(node.kind == "matmul" and k == 1)
+    return {
+        nm: u == {True} for nm, u in uses.items()
+        if True in u and nm in graph.inputs and graph.inputs[nm].role == "param"
+    }
+
+
+def _weight_views(graph: GraphSpec, cfg, params) -> Dict[str, Any]:
+    """Each 2-D matmul weight of the graph as a
+    :class:`~repro.kernels.matmul.WeightView` of the stored param: a
+    layer offset into the stacked leaf, whole heads of ``wq``/``wk``/``wv``
+    (``[L, d, H, hd]``), the tied head as ``embed`` transposed. A weight
+    that any other op reads stays a plain array (see ``decode_inputs``)."""
+    from repro.kernels.matmul import WeightView
+
+    per = _period(cfg)
+    h, hd, d = cfg.num_heads, cfg.head_dim, cfg.d_model
+    views: Dict[str, Any] = {}
+    if cfg.tie_embeddings:
+        views["lm_head"] = WeightView(params["embed"], transposed=True)
+    for i in _graph_layers(graph):
+        sup, slot = i // per, i % per
+        st = params["blocks"][f"l{slot}"]
+        p = f"L{i}."
+        if "attn" in st:
+            ap = st["attn"]
+            for name in ("wq", "wk", "wv"):
+                views[p + name] = WeightView(ap[name], sup)
+            # [L, H, hd, d] -> [L, H·hd, d] merges whole tiles: a bitcast
+            views[p + "wo"] = WeightView.of_layer(ap["wo"].reshape(-1, h * hd, d), sup)
+        if "ssm" in st:
+            for name in ("wx", "wz", "wB", "wC", "wdt"):
+                views[p + name] = WeightView.of_layer(st["ssm"][name], sup)
+            views[p + "ssm_wo"] = WeightView.of_layer(st["ssm"]["wo"], sup)
+        if "mlp" in st:
+            for name, leaf in (("wg", "wg"), ("wu", "wu"), ("wi", "wi"), ("wo2", "wo")):
+                if leaf in st["mlp"]:
+                    views[p + name] = WeightView.of_layer(st["mlp"][leaf], sup)
+    weights = _matmul_weights(graph)
+    return {nm: v for nm, v in views.items()
+            if weights.get(nm) and len(graph.inputs[nm].shape) == 2}
+
+
 def decode_inputs(graph: GraphSpec, cfg, params, cache) -> Dict[str, Any]:
-    """:func:`model_inputs` plus the cache tensors: slice each layer's
-    cache leaves out of the reference pytree (``models.transformer``
-    layout — per-slot dicts stacked over super-blocks) onto the graph's
-    per-layer cache-in names."""
+    """:func:`model_inputs` plus the cache tensors, for one decode step.
+
+    The cache leaves are sliced per layer out of the reference pytree
+    (``models.transformer`` layout — per-slot dicts stacked over
+    super-blocks) onto the graph's per-layer cache-in names. Each 2-D
+    matmul weight is bound as a :class:`~repro.kernels.matmul.WeightView`
+    of the stored param, which the ``matmul/tile`` kernel reads where it
+    lies: no weight is sliced or relaid out per step (:func:`bind_report`
+    counts). Weights read by anything but the kernel (its XLA fallback
+    included) get the plain slice, which XLA fuses into its own op.
+
+    On a mesh (a graph space with mesh axes) every input is a plain
+    array, as :func:`model_inputs` gives it: the executable's
+    ``shard_map`` places each input by its 2-D spec."""
     out = model_inputs(graph, cfg, params)
+    if not graph.space.mesh_shape:
+        out.update(_weight_views(graph, cfg, params))
     per = _period(cfg)
     for i in _graph_layers(graph):
         sup, slot = i // per, i % per
@@ -1355,6 +1416,35 @@ def decode_inputs(graph: GraphSpec, cfg, params, cache) -> Dict[str, Any]:
             out[f"{p}ssm_state"] = leaf["ssm"][sup]
             out[f"{p}conv_state"] = leaf["conv"][sup]
     return out
+
+
+@dataclasses.dataclass(frozen=True)
+class BindReport:
+    """How one decode step binds its matmul weights, and the bytes each
+    kind moves per step. *In place*: the kernel (or XLA's dot) reads the
+    stored param where it lies — a :class:`~repro.kernels.matmul.WeightView`
+    or the stored array itself. *Copied*: a slice or relayout of a stored
+    param, which XLA writes out every step before a kernel can read it."""
+
+    in_place: int
+    in_place_bytes: int
+    copied: int
+    copied_bytes: int
+
+
+def bind_report(graph: GraphSpec, params, inputs: Mapping[str, Any]) -> BindReport:
+    """Classify the matmul weights of ``inputs`` (as :func:`decode_inputs`
+    bound them from ``params``; works on tracers inside the step's jit)."""
+    from repro.kernels.matmul import WeightView
+
+    stored = {id(x) for x in jax.tree.leaves(params)}
+    counts = {True: [0, 0], False: [0, 0]}
+    for nm in _matmul_weights(graph):
+        w = inputs[nm]
+        here = isinstance(w, WeightView) or id(w) in stored
+        counts[here][0] += 1
+        counts[here][1] += math.prod(w.shape) * jnp.dtype(w.dtype).itemsize
+    return BindReport(*counts[True], *counts[False])
 
 
 def decode_cache(graph: GraphSpec, cfg, outputs: Sequence[Any], cache):

@@ -82,6 +82,9 @@ class ServeEngine:
             # order (measured beats planned, newest measurement wins)
             tune.load_into(tune.default_cache(), self.tune_service)
         self.params = None
+        #: how the last traced decode step bound its matmul weights
+        #: (``axe.compile.BindReport``: in place vs copied, bytes per step)
+        self.bind_report = None
         self._compiled: Dict[tuple, Any] = {}
         self._warned: set = set()
         self._decode = self._scheduled(jax.jit(self.api.decode_step))
@@ -233,16 +236,18 @@ class ServeEngine:
         """The jitted serving step ``(params, cache, tok, pos) ->
         (logits, new_cache)`` around :meth:`compiled_decode`. Binding the
         stacked params and cache onto the graph's per-layer inputs
-        (``decode_inputs``/``decode_cache``) happens inside the jit, so a
-        step never materializes a second copy of the weights, and the
-        cache argument is donated so the new cache reuses its buffers.
-        Its device ops are named by scope (docs/serving.md, "Tracing a
-        server"): ``bind`` (weight and cache slices, the tied head's
-        transpose), ``restack`` (the new cache), and each graph op's
+        (``decode_inputs``/``decode_cache``) happens inside the jit, and
+        each matmul weight is bound as a view of the stored param that the
+        kernel reads in place, so a step copies no weight; the cache
+        argument is donated so the new cache reuses its buffers. How the
+        weights were bound lands on :attr:`bind_report` when the step
+        traces. Its device ops are named by scope (docs/serving.md,
+        "Tracing a server"): ``bind`` (the per-layer cache slices; no
+        weight slices), ``restack`` (the new cache), and each graph op's
         ``<kind>/<node>`` from the executable.
         On a mesh the new cache keeps the placed cache's shardings, so the
         next step reuses this executable and the donation aliases."""
-        from repro.axe.compile import decode_cache, decode_inputs
+        from repro.axe.compile import bind_report, decode_cache, decode_inputs
 
         key = ("decode_fn", batch or self.batch_size)
         fn = self._compiled.get(key)
@@ -253,6 +258,7 @@ class ServeEngine:
             def step(params, cache, tok, pos):
                 with jax.named_scope("bind"):
                     inputs = decode_inputs(exe.graph, cfg, params, cache)
+                self.bind_report = bind_report(exe.graph, params, inputs)
                 outs = exe.apply(inputs, tok, pos)
                 logits = dict(zip(exe.graph.outputs(), outs))["logits"]
                 with jax.named_scope("restack"):

@@ -83,6 +83,34 @@ def test_matmul_tile_compiles(one_chip, as_chip, m, k, n):
     assert _kernels(compiled) == 1
 
 
+@pytest.mark.parametrize("stored,layer,transposed", [
+    ((36, D, FF), 3, False),          # layer offset: MLP gate/up projection
+    ((36, FF, D), 35, False),         # layer offset: MLP down projection
+    ((36, D, H, HD), 1, False),       # head split: q projection
+    ((36, D, KV, HD), 2, False),      # head split: k/v projections
+    ((64, 2560, 80), 5, False),       # mamba2's dt projection: stored K-minor
+    ((V, D), None, True),             # transposed: the tied head
+])
+def test_matmul_tile_reads_weight_views(one_chip, as_chip, stored, layer, transposed):
+    """The kernel reads each form of weight view at 16 rows (the decode
+    step's batch) from the stored array itself: one kernel, no copy."""
+    from repro.kernels import programs
+    from repro.kernels.matmul import WeightView
+
+    def view(w):
+        if transposed:
+            return WeightView(w, transposed=True)
+        return WeightView(w, layer) if len(stored) == 4 else WeightView.of_layer(w, layer)
+
+    w = _sds(stored, jnp.bfloat16, one_chip)
+    a = _sds((16, stored[-1] if transposed else stored[1]), jnp.bfloat16, one_chip)
+    with scope(Scope.DEVICE):
+        compiled = jax.jit(lambda a, w: programs.matmul(a, view(w))).lower(a, w).compile()
+    text = compiled.as_text()
+    assert _kernels(compiled) == 1
+    assert " copy(" not in text and "slice" not in text and "transpose" not in text
+
+
 @pytest.mark.parametrize("rows", [PROMPT, B])
 def test_rmsnorm_compiles(one_chip, as_chip, rows):
     from repro.kernels import programs

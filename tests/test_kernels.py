@@ -43,6 +43,38 @@ def test_matmul_matches_ref(dtype, m, k, n, bm, bn, bk):
     )
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize(
+    "stored,layer,form",
+    [
+        # N = 384 and 640 are multiples of 128 that the default bn (256)
+        # does not divide, like qwen3's vocabulary (151,936 = 1,187 · 128)
+        ((3, 256, 384), 2, "layer"),        # layer offset
+        ((3, 256, 80), 1, "layer"),         # narrower than a lane: the stack transposed
+        ((2, 256, 32, 128), 1, "heads"),    # head split, query heads
+        ((3, 256, 8, 128), 2, "heads"),     # head split, GQA kv heads
+        ((640, 256), None, "transposed"),   # a tied head: embed^T
+    ],
+)
+def test_matmul_reads_weight_views(dtype, stored, layer, form):
+    """The kernel reads each form of weight view where it lies, at the
+    decode step's 16 rows, and matches the XLA dot on the sliced weight."""
+    from repro.kernels.matmul import WeightView
+
+    k1, k2 = jax.random.split(jax.random.PRNGKey(3))
+    w = _rand(k2, stored, dtype)
+    view = {"layer": lambda: WeightView.of_layer(w, layer),
+            "heads": lambda: WeightView(w, layer),
+            "transposed": lambda: WeightView(w, transposed=True)}[form]()
+    a = _rand(k1, (16, view.shape[0]), dtype)
+    got = jax.jit(lambda a, v: programs.matmul(a, v, stage="tile", impl="kernel"))(a, view)
+    want = ref.matmul_ref(a, view.materialize())
+    assert got.shape == (16, view.shape[1])
+    np.testing.assert_allclose(
+        got.astype(jnp.float32), want.astype(jnp.float32), **_tol(dtype)
+    )
+
+
 # ---------------------------------------------------------------------------
 # flash attention
 # ---------------------------------------------------------------------------

@@ -208,6 +208,129 @@ def test_decode_step_per_slot_positions():
 
 
 # ---------------------------------------------------------------------------
+# weights bound in place: decode_inputs' views vs plain per-layer slices
+# ---------------------------------------------------------------------------
+
+#: every product in the matmul kernel (interpret mode), as on the chip
+KERNELS = {"matmul/tile": "kernel"}
+#: the benchmark cells' architectures at smoke size, heads tied as there:
+#: qk-norm + GQA, and the SSD mixer; their matmul weights (2 layers + head)
+BOUND_WEIGHTS = {"qwen3-4b": 2 * 7 + 1, "mamba2-2.7b": 2 * 6 + 1}
+
+
+def _tied_setup(arch):
+    key = (arch, "tied")
+    if key not in _SETUP:
+        cfg = dataclasses.replace(_cfg(arch), tie_embeddings=True)
+        api = build_model(cfg)
+        _SETUP[key] = (cfg, api, api.init(jax.random.PRNGKey(0)))
+    return _SETUP[key]
+
+
+def _sliced_decode_inputs(graph, cfg, params, cache):
+    """The binding before weight views: every weight a plain slice,
+    relayout or transpose of the stored params (``model_inputs``)."""
+    return {**axe.decode_inputs(graph, cfg, params, cache),
+            **axe.model_inputs(graph, cfg, params)}
+
+
+@pytest.mark.parametrize("fuse", [False, True])
+@pytest.mark.parametrize("arch", sorted(BOUND_WEIGHTS))
+def test_decode_step_weight_views_match_plain_slices(arch, fuse):
+    """The served step, whose kernels read each weight where it lies in
+    the stored params, gives the logits and new cache that the same
+    executable gives on plain per-layer slices — step after step, slots
+    at different positions — and ``bind_report`` counts no weight copied
+    (the plain binding copies every one). With ``fuse`` the products
+    carry their fused epilogues into the kernel. Equal up to f32
+    rounding: a transposed view contracts B's last dim, which the
+    interpreted dot sums in another order."""
+    from repro import tune
+
+    cfg, api, params = _tied_setup(arch)
+    _, cache, tok = _prefill(api, cfg)
+    eng = ServeEngine(api=api, batch_size=B, max_seq=MAX_SEQ,
+                      force_schedule=KERNELS, fuse=fuse)
+    eng.load(params)
+    exe = eng.compiled_decode()
+
+    @jax.jit
+    def sliced_step(params, cache, tok, pos):
+        outs = exe.apply(_sliced_decode_inputs(exe.graph, cfg, params, cache),
+                         tok, pos)
+        logits = dict(zip(exe.graph.outputs(), outs))["logits"]
+        return logits, axe.decode_cache(exe.graph, cfg, outs, cache)
+
+    c_view, c_sliced = jax.tree.map(jnp.copy, cache), cache
+    for t in range(3):
+        pos = jnp.asarray([S0 + t, S0 + 2 * t + 3], jnp.int32)
+        got, c_view = eng.decode_step(tok, c_view, pos)
+        with tune.force_schedule(KERNELS):
+            want, c_sliced = sliced_step(params, c_sliced, tok, pos)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+        assert _cache_maxdiff(c_view, c_sliced) < 1e-5
+        tok = jnp.argmax(got, axis=-1).astype(jnp.int32)
+
+    n = BOUND_WEIGHTS[arch]
+    rep = eng.bind_report
+    assert (rep.in_place, rep.copied, rep.copied_bytes) == (n, 0, 0)
+    sliced = axe.bind_report(exe.graph, params,
+                             _sliced_decode_inputs(exe.graph, cfg, params, cache))
+    assert (sliced.in_place, sliced.copied) == (0, n)
+    assert sliced.copied_bytes == rep.in_place_bytes > 0
+    # the products ran in the kernel, a view's layer its one SMEM scalar
+    jaxpr = str(jax.make_jaxpr(eng.decode_fn())(params, cache, tok, pos))
+    assert "matmul_tile" in jaxpr and "Ref<smem>{i32[1]}" in jaxpr
+
+
+def _plain_model_inputs(graph, cfg, params):
+    """``model_inputs`` for a dense config, as it read before decode-step
+    views existed: per-layer slices, q/k/v/o relaid out to 2-D, the tied
+    head transposed."""
+    d, h, kv, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    out = {"embed": params["embed"], "final_norm": params["final_norm"],
+           "lm_head": params["embed"].T}
+    for i in range(cfg.num_layers):
+        lp = jax.tree.map(lambda a: a[i], params["blocks"]["l0"])
+        ap, mp, p = lp["attn"], lp["mlp"], f"L{i}."
+        out.update({
+            p + "norm1": lp["norm1"],
+            p + "wq": ap["wq"].reshape(d, h * hd),
+            p + "wk": ap["wk"].reshape(d, kv * hd),
+            p + "wv": ap["wv"].reshape(d, kv * hd),
+            p + "wo": ap["wo"].reshape(h * hd, d),
+            p + "q_norm": ap["q_norm"], p + "k_norm": ap["k_norm"],
+            p + "norm2": lp["norm2"],
+            p + "wg": mp["wg"], p + "wu": mp["wu"], p + "wo2": mp["wo"],
+        })
+    return out
+
+
+def test_compiled_forward_binding_unchanged():
+    """``model_inputs`` (score(), the compiled forward, training) keeps
+    plain arrays: with every product in the kernel, its lowered program
+    is the one the plain per-layer binding gives, op for op."""
+    from repro import tune
+
+    cfg, _, params = _tied_setup("qwen3-4b")
+    exe = axe.model_executable(cfg, None, B, 8)
+    tokens = jnp.zeros((B * 8,), jnp.int32)
+
+    def lowered(bind):
+        def forward(p, t):
+            return exe.apply(bind(exe.graph, cfg, p), t)
+
+        with tune.force_schedule(KERNELS):
+            jaxpr = str(jax.make_jaxpr(forward)(params, tokens))
+            return jaxpr, jax.jit(forward).lower(params, tokens).as_text()
+
+    jaxpr, text = lowered(axe.model_inputs)
+    assert "matmul_tile" in jaxpr and "Ref<smem>{i32[1]}" not in jaxpr
+    assert text == lowered(_plain_model_inputs)[1]
+
+
+# ---------------------------------------------------------------------------
 # ServeEngine.generate: compiled decode is the default path
 # ---------------------------------------------------------------------------
 
