@@ -140,7 +140,6 @@ def run_overlap(batch: int, seq: int) -> list:
 
     arch = "qwen3-moe-235b-a22b"
     cfg = smoke_variant(get_config(arch))
-    cfg = dataclasses.replace(cfg, capacity_factor=float(cfg.num_experts))
     api = build_model(cfg)
     params = api.init(jax.random.PRNGKey(0))
     tokens = jax.random.randint(
@@ -253,8 +252,6 @@ def run(batch: int, seq: int, *, fuse: bool = True) -> list:
     rows = []
     for arch in ARCHS:
         cfg = smoke_variant(get_config(arch))
-        if cfg.is_moe:
-            cfg = dataclasses.replace(cfg, capacity_factor=float(cfg.num_experts))
         api = build_model(cfg)
         params = api.init(jax.random.PRNGKey(0))
         tokens = jax.random.randint(

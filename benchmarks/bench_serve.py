@@ -69,8 +69,6 @@ def run(slots: int, max_seq: int, n_requests: int, new_tokens: int,
     rows = []
     for arch in archs:
         cfg = smoke_variant(get_config(arch))
-        if cfg.is_moe:
-            cfg = dataclasses.replace(cfg, capacity_factor=float(cfg.num_experts))
         api = build_model(cfg)
         params = api.init(jax.random.PRNGKey(0))
         engine = ServeEngine(api=api, batch_size=slots, max_seq=max_seq)

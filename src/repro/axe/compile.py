@@ -297,21 +297,21 @@ def _exec_attention(ctx: ExecCtx, q, k, v):
 
 @register_op_backend("moe_dispatch")
 def _exec_moe_dispatch(ctx: ExecCtx, x):
-    """Capacity routing on this device's token shard, then the plan's
-    expert-axis exchange: AllToAll steps swap capacity buffers with the
-    other token shards on the axis (classic expert parallelism);
-    DynamicSlice steps keep only this device's expert chunk."""
+    """Dropless routing of this device's token shard over every expert
+    of the router, into one row per token of each held expert
+    (``models.moe``), then the plan's expert-axis exchange: AllToAll
+    steps swap buffers with the other token shards on the axis (classic
+    expert parallelism); DynamicSlice steps keep only this device's
+    expert chunk. Stashes the gates for the combine and the layer's load
+    (``moe.load``, summed over the token shards) for ``side_output``."""
     from repro.models import moe as moe_mod
 
-    e = int(ctx.attr("experts"))
-    c = int(ctx.attr("capacity"))
-    k = int(ctx.attr("experts_per_tok", 1))
-    router = ctx.aux(ctx.attr("router"))
-    t_axes = ctx.in_specs[0].placement()[0]
-    c_src = c // ctx.ext(t_axes)
-    buf, meta = moe_mod.local_dispatch(
-        x, router, num_experts=e, experts_per_tok=k, capacity=c_src
+    r = moe_mod.route(
+        x, ctx.aux(ctx.attr("router")),
+        experts_per_tok=int(ctx.attr("experts_per_tok", 1)),
+        held=int(ctx.attr("experts")), offset=int(ctx.attr("expert_offset", 0)),
     )
+    buf = moe_mod.dispatch(x, r.routed)
     for step in ctx.shape_steps:
         if isinstance(step, coll.AllToAll):
             buf = jax.lax.all_to_all(
@@ -325,17 +325,16 @@ def _exec_moe_dispatch(ctx: ExecCtx, x):
             )
         else:  # pragma: no cover - the rule only emits the two above
             raise CompileError(f"{ctx.node.name}: unexpected dispatch step {step}")
-    ctx.side[ctx.node.out] = {
-        "meta": meta, "tokens": x.shape[0], "d": x.shape[1],
-    }
+    load = moe_mod.load(r.routed, ctx.in_specs[0].placement()[0])
+    ctx.side[ctx.node.out] = {"gates": r.gates, "load": load}
     return buf
 
 
 @register_op_backend("moe_combine")
 def _exec_moe_combine(ctx: ExecCtx, oe):
-    """Unwinds the dispatch exchange (reverse step order), then combines
-    this device's own tokens with the routing metadata the dispatch
-    backend stashed."""
+    """Unwinds the dispatch exchange (reverse step order), then sums
+    each of this device's tokens over the held experts it picked,
+    weighted by the gates the dispatch backend stashed."""
     from repro.models import moe as moe_mod
 
     side = ctx.side.get(ctx.attr("dispatch"))
@@ -354,8 +353,7 @@ def _exec_moe_combine(ctx: ExecCtx, oe):
             oe = jax.lax.all_gather(oe, step.axis, axis=step.dim, tiled=True)
         else:  # pragma: no cover
             raise CompileError(f"{ctx.node.name}: unexpected combine step {step}")
-    y = moe_mod.local_combine(oe, side["meta"], side["tokens"], side["d"])
-    return y.astype(ctx.out_spec_dtype())
+    return moe_mod.combine(oe, side["gates"]).astype(ctx.out_spec_dtype())
 
 
 @register_op_backend("ssm_mix")
@@ -1351,12 +1349,15 @@ def _matmul_weights(graph: GraphSpec) -> Dict[str, bool]:
 
 
 def _weight_views(graph: GraphSpec, cfg, params) -> Dict[str, Any]:
-    """Each 2-D matmul weight of the graph as a
-    :class:`~repro.kernels.matmul.WeightView` of the stored param: a
-    layer offset into the stacked leaf, whole heads of ``wq``/``wk``/``wv``
-    (``[L, d, H, hd]``), the tied head as ``embed`` transposed. A weight
-    that any other op reads stays a plain array (see ``decode_inputs``)."""
+    """Each matmul weight of the graph as a view of the stored param: a
+    :class:`~repro.kernels.matmul.WeightView` of a 2-D weight (a layer
+    offset into the stacked leaf, whole heads of ``wq``/``wk``/``wv``
+    (``[L, d, H, hd]``), the tied head as ``embed`` transposed), an
+    :class:`~repro.kernels.moe_gemm.ExpertView` of a layer's expert
+    weights (``[L, E, d, f]``). A weight that any other op reads stays a
+    plain array (see ``decode_inputs``)."""
     from repro.kernels.matmul import WeightView
+    from repro.kernels.moe_gemm import ExpertView
 
     per = _period(cfg)
     h, hd, d = cfg.num_heads, cfg.head_dim, cfg.d_model
@@ -1381,9 +1382,11 @@ def _weight_views(graph: GraphSpec, cfg, params) -> Dict[str, Any]:
             for name, leaf in (("wg", "wg"), ("wu", "wu"), ("wi", "wi"), ("wo2", "wo")):
                 if leaf in st["mlp"]:
                     views[p + name] = WeightView.of_layer(st["mlp"][leaf], sup)
+        if "moe" in st:
+            for name in ("wg", "wu", "wo"):
+                views[p + "moe_" + name] = ExpertView(st["moe"][name], sup)
     weights = _matmul_weights(graph)
-    return {nm: v for nm, v in views.items()
-            if weights.get(nm) and len(graph.inputs[nm].shape) == 2}
+    return {nm: v for nm, v in views.items() if weights.get(nm)}
 
 
 def decode_inputs(graph: GraphSpec, cfg, params, cache) -> Dict[str, Any]:
@@ -1391,12 +1394,13 @@ def decode_inputs(graph: GraphSpec, cfg, params, cache) -> Dict[str, Any]:
 
     The cache leaves are sliced per layer out of the reference pytree
     (``models.transformer`` layout — per-slot dicts stacked over
-    super-blocks) onto the graph's per-layer cache-in names. Each 2-D
-    matmul weight is bound as a :class:`~repro.kernels.matmul.WeightView`
-    of the stored param, which the ``matmul/tile`` kernel reads where it
-    lies: no weight is sliced or relaid out per step (:func:`bind_report`
-    counts). Weights read by anything but the kernel (its XLA fallback
-    included) get the plain slice, which XLA fuses into its own op.
+    super-blocks) onto the graph's per-layer cache-in names. Each matmul
+    weight is bound as a view of the stored param (:func:`_weight_views`),
+    which the ``matmul/tile`` or ``moe_gemm/expert_gemm`` kernel reads
+    where it lies: no weight is sliced or relaid out per step
+    (:func:`bind_report` counts). Weights read by anything but the kernel
+    (its XLA fallback included) get the plain slice, which XLA fuses into
+    its own op.
 
     On a mesh (a graph space with mesh axes) every input is a plain
     array, as :func:`model_inputs` gives it: the executable's
@@ -1422,9 +1426,10 @@ def decode_inputs(graph: GraphSpec, cfg, params, cache) -> Dict[str, Any]:
 class BindReport:
     """How one decode step binds its matmul weights, and the bytes each
     kind moves per step. *In place*: the kernel (or XLA's dot) reads the
-    stored param where it lies — a :class:`~repro.kernels.matmul.WeightView`
-    or the stored array itself. *Copied*: a slice or relayout of a stored
-    param, which XLA writes out every step before a kernel can read it."""
+    stored param where it lies — a :class:`~repro.kernels.matmul.WeightView`,
+    an :class:`~repro.kernels.moe_gemm.ExpertView` or the stored array
+    itself. *Copied*: a slice or relayout of a stored param, which XLA
+    writes out every step before a kernel can read it."""
 
     in_place: int
     in_place_bytes: int
@@ -1436,12 +1441,13 @@ def bind_report(graph: GraphSpec, params, inputs: Mapping[str, Any]) -> BindRepo
     """Classify the matmul weights of ``inputs`` (as :func:`decode_inputs`
     bound them from ``params``; works on tracers inside the step's jit)."""
     from repro.kernels.matmul import WeightView
+    from repro.kernels.moe_gemm import ExpertView
 
     stored = {id(x) for x in jax.tree.leaves(params)}
     counts = {True: [0, 0], False: [0, 0]}
     for nm in _matmul_weights(graph):
         w = inputs[nm]
-        here = isinstance(w, WeightView) or id(w) in stored
+        here = isinstance(w, (WeightView, ExpertView)) or id(w) in stored
         counts[here][0] += 1
         counts[here][1] += math.prod(w.shape) * jnp.dtype(w.dtype).itemsize
     return BindReport(*counts[True], *counts[False])
@@ -1466,6 +1472,17 @@ def decode_cache(graph: GraphSpec, cfg, outputs: Sequence[Any], cache):
             for key, g in names.items()
         }
     return new
+
+
+def decode_expert_load(graph: GraphSpec, outputs: Sequence[Any]):
+    """The decode step's expert load from its output tuple: ``[layers,
+    2]`` int32, per expert layer ``models.moe.load`` (routed (token, held
+    expert) pairs, held experts with a token); None for a graph without
+    experts."""
+    vals = dict(zip(graph.outputs(), outputs))
+    loads = [vals[n] for n in (f"L{i}.moe_load" for i in _graph_layers(graph))
+             if n in vals]
+    return jnp.stack(loads) if loads else None
 
 
 def decode_executable(

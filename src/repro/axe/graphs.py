@@ -125,15 +125,6 @@ class _Builder:
                          tuple(self.extra_outputs))
 
 
-def capacity(tokens: int, cfg) -> int:
-    """Per-expert MoE capacity — the jax-free twin of
-    ``repro.models.moe.capacity`` (parity asserted in tests) so graph
-    metadata matches what the reference models and the compiled
-    executor actually allocate."""
-    c = int(tokens * cfg.experts_per_tok * cfg.capacity_factor / cfg.num_experts)
-    return max(8, -(-c // 8) * 8)
-
-
 def _layer_window(cfg, i: int):
     """Per-layer sliding window, mirroring ``models.transformer``:
     local/global families window the first ``ratio`` layers of each
@@ -250,8 +241,9 @@ def _ffn_block(b: _Builder, cfg, t: int, p: str, x_in: str, res: str) -> str:
     x2 = b.op(f"{p}norm_ffn", "norm", (x_in,), f"{p}x2",
               attrs=(("weight", f"{p}norm2"),))
     if cfg.is_moe:
-        e, f_e = cfg.num_experts, cfg.moe_d_ff
-        cap = capacity(t, cfg)
+        # the experts held here (all, or this device's share); the router
+        # keeps every expert. One buffer row per token: no drops
+        e, f_e = cfg.moe_experts_held, cfg.moe_d_ff
         moe_wg = b.inp(f"{p}moe_wg", (e, d, f_e), "param",
                        [("model", None, None), (None, None, "model"),
                         (None, None, None)])
@@ -262,8 +254,9 @@ def _ffn_block(b: _Builder, cfg, t: int, p: str, x_in: str, res: str) -> str:
                        [("model", None, None), (None, "model", None),
                         (None, None, None)])
         xe = b.op(f"{p}moe_dispatch", "moe_dispatch", (x2,), f"{p}xe",
-                  attrs=(("experts", e), ("capacity", cap),
+                  attrs=(("experts", e), ("capacity", t),
                          ("experts_per_tok", cfg.experts_per_tok),
+                         ("expert_offset", cfg.expert_offset),
                          ("router", f"{p}router")))
         hg = b.op(f"{p}moe_ffn_g", "matmul", (xe, moe_wg), f"{p}hg")
         hu = b.op(f"{p}moe_ffn_u", "matmul", (xe, moe_wu), f"{p}hu")
@@ -273,7 +266,7 @@ def _ffn_block(b: _Builder, cfg, t: int, p: str, x_in: str, res: str) -> str:
         out = b.op(f"{p}moe_combine", "moe_combine", (oe,), f"{p}moe_out",
                    attrs=(("tokens", t), ("dispatch", f"{p}xe"),
                           ("dispatch_input", f"{p}x2"),
-                          ("experts", e), ("capacity", cap)))
+                          ("experts", e), ("capacity", t)))
         return b.op(f"{p}ffn_residual", "elementwise", (out, res), f"{p}x_out",
                     attrs=(("fn", "add"),))
     if cfg.mlp_type == "swiglu":
@@ -579,6 +572,12 @@ def decode_graph(
                                         layer_index=i)
         if cfg.is_moe or cfg.d_ff:
             x = _ffn_block(b, cfg, batch, p, x, x)
+        if cfg.is_moe:
+            # the expert layer's load (``moe.load``): a graph output read
+            # after serving, never inside a step
+            b.op(f"{p}moe_load_out", "side_output", (f"{p}xe",), f"{p}moe_load",
+                 attrs=(("side", f"{p}xe"), ("channel", "load"),
+                        ("shape", (2,)), ("dtype", "int32")))
 
     x_f = b.op("final_norm", "norm", (x,), "x_f",
                attrs=(("weight", "final_norm"),))

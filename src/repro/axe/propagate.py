@@ -302,12 +302,14 @@ def _dispatch_token_axes(
 
 
 def rule_moe_dispatch(node: OpNode, x: AxeSpec):
-    """Capacity routing [T, d] → [E, C, d] with expert parallelism: the
+    """Dropless routing [T, d] → [E, C, d] with expert parallelism: E is
+    the experts held (``attrs['experts']``), C the token count
+    (``attrs['capacity']``: one row per token, so nothing drops); the
     expert dim shards over the axes named by ``attrs['expert_axes']``
     (default: the 'model' axis when it divides E).
 
     Executable semantics (``axe.compile``): each token shard routes its
-    own tokens into per-expert capacity slots, so the capacity dim
+    own tokens into its rows of every held expert, so the row dim
     carries the token axes. An expert axis the tokens are *also*
     sharded over exchanges buffers (AllToAll — the classic EP
     dispatch); an expert axis the tokens are replicated over just keeps
@@ -737,11 +739,17 @@ def rule_ssm_decode(node: OpNode, x: AxeSpec, b: AxeSpec, c: AxeSpec,
 
 def rule_side_output(node: OpNode, x: AxeSpec, env=None):
     """A boundary node surfacing a tensor the producing op computed on
-    the side (the SSD mixer's advanced states): shape and dtype come
-    from the cache-in tensor named by ``attrs['like']``; the batch
+    the side (the SSD mixer's advanced states, the expert layer's load):
+    shape and dtype come from the cache-in tensor named by
+    ``attrs['like']``, or are ``attrs['shape']``/``['dtype']`` of a
+    tensor every device holds whole (replicated); the batch
     placement follows the producing op's output (the states were
     aligned to it inside the producer's rule) and no data moves."""
     like = node.attr("like")
+    if like is None and node.attr("shape") is not None:
+        # a small tensor every device holds whole (the expert load)
+        return AxeSpec.replicated(tuple(node.attr("shape")), x.space,
+                                  node.attr("dtype")), ()
     if env is None or like not in env:
         raise PropagationError(
             f"{node.name}: side_output needs attrs['like'] naming a "

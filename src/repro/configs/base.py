@@ -18,10 +18,13 @@ class ModelConfig:
     head_dim: int = 0               # 0 -> d_model // num_heads
 
     # --- MoE ---
-    num_experts: int = 0
+    num_experts: int = 0            # the router's width: every expert of a layer
     experts_per_tok: int = 0
     expert_d_ff: int = 0            # fine-grained expert hidden (0 -> d_ff)
-    capacity_factor: float = 1.25
+    # the share of each layer's experts this device holds (expert
+    # parallelism): experts [expert_rank·experts_held, +experts_held)
+    experts_held: int = 0           # 0 -> num_experts (every expert)
+    expert_rank: int = 0
 
     # --- attention flavor ---
     qk_norm: bool = False
@@ -49,6 +52,13 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
+        if self.experts_held:
+            shares = self.num_experts // self.experts_held
+            if shares * self.experts_held != self.num_experts \
+                    or not 0 <= self.expert_rank < shares:
+                raise ValueError(
+                    f"{self.name}: experts_held={self.experts_held}, expert_rank="
+                    f"{self.expert_rank} is no share of {self.num_experts} experts")
 
     # ---- derived ----
     @property
@@ -58,6 +68,15 @@ class ModelConfig:
     @property
     def moe_d_ff(self) -> int:
         return self.expert_d_ff or self.d_ff
+
+    @property
+    def moe_experts_held(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def expert_offset(self) -> int:
+        """Id of the first expert held here."""
+        return self.expert_rank * self.moe_experts_held
 
     @property
     def ssm_d_inner(self) -> int:

@@ -84,19 +84,16 @@ def moe_routing_ref(
     router: np.ndarray,  # [d, E] router weights
     *,
     experts_per_tok: int,
-    capacity: int,
 ):
-    """Loop-based oracle for capacity routing (dispatch → combine),
-    independent of the sort/scatter implementation in ``models.moe``.
+    """Loop-based oracle for dropless routing (dispatch → combine),
+    independent of the vectorised implementation in ``models.moe``.
 
-    Token t's k-th routed copy goes to expert e = top-k(e)(softmax(x_t
-    @ router)); within an expert, slots fill in (token, k) lexicographic
-    order (exactly the stable argsort order the fused dispatch uses) and
-    overflow tokens are dropped. Returns ``(buf, combine)`` where
-    ``buf`` is the dense [E, C, d] dispatch buffer and ``combine(out)``
-    gate-weights and gathers an [E, C, d']-shaped expert output back to
-    [T, d'] (the identity-FFN check: ``combine(buf)`` ≈ the gate-weighted
-    reconstruction of kept tokens)."""
+    Token t picks the experts of the top-k of softmax(x_t @ router), its
+    gates renormalised over the k; each picked expert gets x_t in its row
+    t. Returns ``(buf, combine)`` where ``buf`` is the dense [E, T, d]
+    dispatch buffer and ``combine(out)`` gate-weights an [E, T, d']
+    expert output back to [T, d'] (the identity-FFN check:
+    ``combine(buf)`` ≈ the gate-weighted reconstruction of the tokens)."""
     x = np.asarray(x, np.float32)
     router = np.asarray(router, np.float32)
     t, d = x.shape
@@ -105,24 +102,21 @@ def moe_routing_ref(
     z = np.exp(logits - logits.max(axis=-1, keepdims=True))
     probs = z / z.sum(axis=-1, keepdims=True)
 
-    buf = np.zeros((e, capacity, d), np.float32)
-    assignments = []  # (token, expert, slot, gate)
-    fill = np.zeros(e, np.int64)
+    buf = np.zeros((e, t, d), np.float32)
+    assignments = []  # (token, expert, gate)
     for ti in range(t):
         order = np.argsort(-probs[ti], kind="stable")[:experts_per_tok]
         gates = probs[ti][order]
         gates = gates / gates.sum()
         for ei, g in zip(order, gates):
-            if fill[ei] < capacity:
-                buf[ei, fill[ei]] = x[ti]
-                assignments.append((ti, int(ei), int(fill[ei]), float(g)))
-                fill[ei] += 1
+            buf[ei, ti] = x[ti]
+            assignments.append((ti, int(ei), float(g)))
 
     def combine(out):
         out = np.asarray(out, np.float32)
         y = np.zeros((t, out.shape[-1]), np.float32)
-        for ti, ei, slot, g in assignments:
-            y[ti] += g * out[ei, slot]
+        for ti, ei, g in assignments:
+            y[ti] += g * out[ei, ti]
         return y
 
     return buf, combine

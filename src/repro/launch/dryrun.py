@@ -280,7 +280,6 @@ def execute_cell(
     carries the hidden/exposed comm-second split, and the issued==planned
     cross-check runs against the interleaved issue order."""
     import contextlib
-    import dataclasses as _dc
 
     import numpy as np
 
@@ -302,10 +301,6 @@ def execute_cell(
         record.update(status="skipped",
                       reason=f"family {cfg.family} has no model binding")
         return record
-    if cfg.is_moe:
-        # drop-free capacity: local (sharded) and global (reference)
-        # routing then agree exactly, so the numeric check is strict
-        cfg = _dc.replace(cfg, capacity_factor=float(cfg.num_experts))
 
     # unlike the deviceless lowering modes, --execute RUNS the numerics:
     # cap the mesh at 8 devices even when this module's default 512

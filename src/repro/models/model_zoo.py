@@ -104,6 +104,6 @@ def build_model(cfg: ModelConfig) -> ModelAPI:
         init=lambda key: tf_mod.lm_init(cfg, key),
         loss_fn=lambda p, b: tf_mod.lm_loss(p, b, cfg),
         cache_init=lambda batch, max_seq: tf_mod.cache_init(cfg, batch, max_seq),
-        prefill=lambda p, b, c: tf_mod.prefill(p, b, c, cfg),
+        prefill=lambda p, b, c, **kw: tf_mod.prefill(p, b, c, cfg, **kw),
         decode_step=lambda p, t, c, pos: tf_mod.decode_step(p, t, c, pos, cfg),
     )

@@ -1,16 +1,26 @@
 """Mixture-of-Experts layer (paper §4.1 workload family).
 
-Sort-based capacity dispatch (megablocks/maxtext-style, TPU-friendly):
-top-k routing, argsort token→expert assignments, scatter into a dense
-[E, C, d] buffer (tokens over capacity are dropped), two grouped GEMMs
-(SwiGLU), gather + gate-weighted combine. The [E, C, d] buffer is what
-the Pallas ``moe_gemm`` kernel consumes; under SPMD the E dim is
-sharded over the ``model`` axis (expert parallelism), so the scatter
-lowers to an all-to-all — exactly the collective the Axe layout pair
-(tokens: batch-sharded → buffer: expert-sharded) infers.
+Dropless dense dispatch: top-k routing over every expert of the router,
+then a buffer of one row per token for each expert held here,
+``[E_held, T, d]``, holding the token where it picked that expert and
+zeros elsewhere. One token never picks an expert twice, so no token is
+ever dropped, at any load. Two grouped GEMMs (SwiGLU) run on the buffer,
+and each token's output is the gate-weighted sum over the held experts
+it picked. The buffer is what the Pallas ``moe_gemm`` kernel consumes;
+under SPMD its expert dim is sharded over the ``model`` axis (expert
+parallelism), so the dispatch lowers to an all-to-all — exactly the
+collective the Axe layout pair (tokens: batch-sharded → buffer:
+expert-sharded) infers.
+
+A device may hold a share of the experts (``cfg.experts_held`` of
+``cfg.num_experts``, the share ``cfg.expert_rank``): the router keeps its
+full width and top-k, and the layer computes only what the held experts
+add. What the other shares would add is left out; nothing stands in for
+them.
 """
 from __future__ import annotations
 
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -20,97 +30,70 @@ from repro.train.act_sharding import constrain
 
 
 def moe_init(key, cfg, dtype) -> Params:
+    """The router over every expert, and the held experts' weights."""
     d, e, ff = cfg.d_model, cfg.num_experts, cfg.moe_d_ff
+    held = cfg.moe_experts_held
     ks = jax.random.split(key, 4)
     return {
         "router": dense_init(ks[0], (d, e), d, jnp.float32),
-        "wg": dense_init(ks[1], (e, d, ff), d, dtype),
-        "wu": dense_init(ks[2], (e, d, ff), d, dtype),
-        "wo": dense_init(ks[3], (e, ff, d), ff, dtype),
+        "wg": dense_init(ks[1], (held, d, ff), d, dtype),
+        "wu": dense_init(ks[2], (held, d, ff), d, dtype),
+        "wo": dense_init(ks[3], (held, ff, d), ff, dtype),
     }
 
 
-def capacity(tokens: int, cfg) -> int:
-    """Per-expert capacity, rounded up to a VREG-sublane multiple."""
-    c = int(tokens * cfg.experts_per_tok * cfg.capacity_factor / cfg.num_experts)
-    return max(8, -(-c // 8) * 8)
+class Routing(NamedTuple):
+    gates: jax.Array     # [T, held] f32: renormalised gate of each held expert picked, else 0
+    routed: jax.Array    # [T, held] bool: the token picked that held expert
+    logits: jax.Array    # [T, E] f32: router logits over every expert
+    experts: jax.Array   # [T, k] int32: the experts picked (ids over every expert)
 
 
-def local_dispatch(
-    xf: jax.Array,
-    router: jax.Array,
-    *,
-    num_experts: int,
-    experts_per_tok: int,
-    capacity: int,
-):
-    """Route + sort + scatter local tokens into a dense [E, C, d] buffer
-    with an explicit per-expert capacity (``axe.compile``'s MoE backend
-    passes the plan's per-shard contribution here).
-
-    Returns (buf, combine_meta) where combine_meta carries what the
-    gather/combine needs. Pure local compute — no collectives.
-    """
-    t, d = xf.shape
-    k, e = experts_per_tok, num_experts
-    logits = xf.astype(jnp.float32) @ router
+def route(xf: jax.Array, router: jax.Array, *, experts_per_tok: int,
+          held: int, offset: int = 0) -> Routing:
+    """Top-k routing of ``xf [T, d]`` over every expert of ``router
+    [d, E]`` (softmax, top-k, gates renormalised over the k), kept to the
+    ``held`` experts ``offset .. offset + held - 1``."""
+    logits = xf.astype(jnp.float32) @ router.astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_idx = jax.lax.top_k(probs, k)
-    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
-
-    tk = t * k
-    flat_expert = expert_idx.reshape(tk)
-    flat_token = jnp.repeat(jnp.arange(t), k)
-    flat_gate = gate_vals.reshape(tk)
-    order = jnp.argsort(flat_expert)
-    sorted_expert = flat_expert[order]
-    sorted_token = flat_token[order]
-    sorted_gate = flat_gate[order]
-
-    counts = jnp.bincount(sorted_expert, length=e)
-    starts = jnp.cumsum(counts) - counts
-    pos_in_expert = jnp.arange(tk) - starts[sorted_expert]
-
-    c = capacity
-    keep = pos_in_expert < c
-    dst = jnp.where(keep, sorted_expert * c + pos_in_expert, e * c)
-
-    buf = jnp.zeros((e * c + 1, d), xf.dtype)
-    buf = buf.at[dst].set(xf[sorted_token], mode="drop")
-    buf = buf[: e * c].reshape(e, c, d)
-    meta = dict(dst=dst, keep=keep, sorted_token=sorted_token,
-                sorted_gate=sorted_gate, probs=probs, expert_idx=expert_idx,
-                logits=logits, c=c)
-    return buf, meta
+    top_p, top = jax.lax.top_k(probs, experts_per_tok)
+    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    hit = top[:, :, None] == offset + jnp.arange(held)          # [T, k, held]
+    gates = jnp.sum(jnp.where(hit, top_p[:, :, None], 0.0), axis=1)
+    return Routing(gates, jnp.any(hit, axis=1), logits, top)
 
 
-def _local_dispatch(xf: jax.Array, router: jax.Array, cfg):
-    t = xf.shape[0]
-    return local_dispatch(
-        xf, router,
-        num_experts=cfg.num_experts,
-        experts_per_tok=cfg.experts_per_tok,
-        capacity=capacity(t, cfg),
-    )
+def dispatch(xf: jax.Array, routed: jax.Array) -> jax.Array:
+    """``[T, d] → [held, T, d]``: expert ``e``'s row ``t`` is token ``t``
+    where it picked ``e``, else zero."""
+    return jnp.where(routed.T[:, :, None], xf[None], jnp.zeros((), xf.dtype))
 
 
-def local_combine(out: jax.Array, meta, t: int, d: int):
-    e = out.shape[0]
-    c = meta["c"]
-    out_flat = out.reshape(e * c, d)
-    gathered = jnp.where(
-        meta["keep"][:, None],
-        out_flat[jnp.clip(meta["dst"], 0, e * c - 1)],
-        0.0,
-    )
-    y = jnp.zeros((t, d), out_flat.dtype)
-    y = y.at[meta["sorted_token"]].add(
-        gathered * meta["sorted_gate"][:, None].astype(out_flat.dtype)
-    )
-    return y
+def combine(out: jax.Array, gates: jax.Array) -> jax.Array:
+    """``[held, T, d] → [T, d]`` in float32: each token's gate-weighted
+    sum of the held experts' outputs (gates are 0 where it did not pick
+    the expert)."""
+    return jnp.sum(out.astype(jnp.float32) * gates.T[:, :, None], axis=0)
 
 
-_local_combine = local_combine
+def load(routed: jax.Array, token_axes=()) -> jax.Array:
+    """``[2]`` int32: the (token, held expert) pairs routed, and the held
+    experts that received at least one token. Inside a ``shard_map``,
+    ``token_axes`` names the mesh axes the tokens are sharded over."""
+    per_expert = jnp.sum(routed, axis=0, dtype=jnp.int32)
+    if token_axes:
+        per_expert = jax.lax.psum(per_expert, tuple(token_axes))
+    return jnp.stack([jnp.sum(per_expert), jnp.sum(per_expert > 0, dtype=jnp.int32)])
+
+
+def _route(xf: jax.Array, router: jax.Array, cfg) -> Routing:
+    return route(xf, router, experts_per_tok=cfg.experts_per_tok,
+                 held=cfg.moe_experts_held, offset=cfg.expert_offset)
+
+
+def expert_load(p: Params, x: jax.Array, cfg) -> jax.Array:
+    """:func:`load` of the layer's routing of ``x [..., d]``."""
+    return load(_route(x.reshape(-1, x.shape[-1]), p["router"], cfg).routed)
 
 
 def _expert_ffn(buf: jax.Array, wg, wu, wo) -> jax.Array:
@@ -122,10 +105,10 @@ def _expert_ffn(buf: jax.Array, wg, wu, wo) -> jax.Array:
 
 def moe_apply_expert_parallel(p: Params, x: jax.Array, cfg, mesh) -> jax.Array:
     """DEVICE-scope MoE (paper §4.1/§4.2 adaptation): the token dim is
-    sharded over (dp × model); each device routes and sorts its own
-    tokens locally (no global sort collectives), then exactly two
-    all_to_alls over `model` move capacity buffers to/from the expert
-    owners. Collective bytes per device ≈ 2 × |local capacity buffer| —
+    sharded over (dp × model); each device routes its own tokens locally
+    (no global sort collectives), then exactly two all_to_alls over
+    `model` move dispatch buffers to/from the expert owners. Collective
+    bytes per device ≈ 2 × |local dispatch buffer| —
     vs. the GSPMD-inferred global-sort dispatch this removed ~97% of the
     collective traffic on qwen3-moe train_4k (see EXPERIMENTS §Perf)."""
     from jax.sharding import PartitionSpec as P
@@ -138,15 +121,14 @@ def moe_apply_expert_parallel(p: Params, x: jax.Array, cfg, mesh) -> jax.Array:
 
     def body(xl, router, wg, wu, wo):
         b_loc, s_loc, d = xl.shape
-        t = b_loc * s_loc
-        xf = xl.reshape(t, d)
-        buf, meta = _local_dispatch(xf, router, cfg)                 # [E, C_loc, d]
+        xf = xl.reshape(b_loc * s_loc, d)
+        r = _route(xf, router, cfg)
+        buf = dispatch(xf, r.routed)                                 # [E, T_loc, d]
         bufx = jax.lax.all_to_all(buf, "model", split_axis=0, concat_axis=1, tiled=True)
-        out = _expert_ffn(bufx, wg, wu, wo)                          # [E_loc, C_loc*ep, d]
+        out = _expert_ffn(bufx, wg, wu, wo)                          # [E_loc, T_loc*ep, d]
         back = jax.lax.all_to_all(out, "model", split_axis=1, concat_axis=0, tiled=True)
-        y = _local_combine(back, meta, t, d)
+        y = combine(back, r.gates)
         return y.reshape(b_loc, s_loc, d).astype(xl.dtype)
-
 
     return jax.shard_map(
         body,
@@ -178,7 +160,7 @@ def _ep_eligible(x: jax.Array, cfg, mesh) -> bool:
         dp_total *= ms[a]
     b, s, _ = x.shape
     return (
-        cfg.num_experts % ep == 0
+        cfg.moe_experts_held % ep == 0
         and s % ep == 0
         and (b % dp_total == 0 or dp_total == 1)
     )
@@ -195,39 +177,9 @@ def moe_apply(
         if _ep_eligible(x, cfg, mesh):
             return moe_apply_expert_parallel(p, x, cfg, mesh)
     b, s, d = x.shape
-    t = b * s
-    k = cfg.experts_per_tok
-    e = cfg.num_experts
-    xf = x.reshape(t, d)
-
-    logits = xf.astype(jnp.float32) @ p["router"]            # [T, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_idx = jax.lax.top_k(probs, k)          # [T, k]
-    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
-
-    # ---- flatten assignments and sort by expert ----
-    tk = t * k
-    flat_expert = expert_idx.reshape(tk)
-    flat_token = jnp.repeat(jnp.arange(t), k)
-    flat_gate = gate_vals.reshape(tk)
-    order = jnp.argsort(flat_expert)
-    sorted_expert = flat_expert[order]
-    sorted_token = flat_token[order]
-    sorted_gate = flat_gate[order]
-
-    # position of each assignment within its expert segment
-    counts = jnp.bincount(sorted_expert, length=e)           # [E]
-    starts = jnp.cumsum(counts) - counts
-    pos_in_expert = jnp.arange(tk) - starts[sorted_expert]
-
-    c = capacity(t, cfg)
-    keep = pos_in_expert < c
-    dst = jnp.where(keep, sorted_expert * c + pos_in_expert, e * c)  # drop bin
-
-    # ---- dispatch: scatter tokens to [E, C, d] ----
-    buf = jnp.zeros((e * c + 1, d), x.dtype)
-    buf = buf.at[dst].set(xf[sorted_token], mode="drop")
-    buf = constrain(buf[: e * c].reshape(e, c, d), "experts", None, None)
+    xf = x.reshape(b * s, d)
+    r = _route(xf, p["router"], cfg)
+    buf = constrain(dispatch(xf, r.routed), "experts", None, None)   # [E_held, T, d]
 
     # ---- grouped expert FFN (SwiGLU) ----
     hg = jnp.einsum("ecd,edf->ecf", buf, p["wg"])
@@ -235,24 +187,17 @@ def moe_apply(
     h = constrain(jax.nn.silu(hg) * hu, "experts", None, None)
     out = constrain(
         jnp.einsum("ecf,efd->ecd", h, p["wo"]), "experts", None, None
-    )  # [E, C, d]
-
-    # ---- combine: gather back and weight by gates ----
-    out_flat = out.reshape(e * c, d)
-    gathered = jnp.where(
-        keep[:, None], out_flat[jnp.clip(dst, 0, e * c - 1)], 0.0
     )
-    y = jnp.zeros((t, d), out_flat.dtype)
-    y = y.at[sorted_token].add(gathered * sorted_gate[:, None].astype(out_flat.dtype))
-    y = constrain(y.reshape(b, s, d).astype(x.dtype), "batch", "seq_res", None)
 
+    y = constrain(combine(out, r.gates).reshape(b, s, d).astype(x.dtype),
+                  "batch", "seq_res", None)
     if return_aux:
-        # load-balance aux loss (Switch-style) + router z-loss
-        me = jnp.mean(probs, axis=0)
-        ce = jnp.mean(
-            jax.nn.one_hot(expert_idx[:, 0], e, dtype=jnp.float32), axis=0
-        )
+        # load-balance aux loss (Switch-style) + router z-loss, over
+        # every expert of the router
+        e = cfg.num_experts
+        me = jnp.mean(jax.nn.softmax(r.logits, axis=-1), axis=0)
+        ce = jnp.mean(jax.nn.one_hot(r.experts[:, 0], e, dtype=jnp.float32), axis=0)
         aux = e * jnp.sum(me * ce)
-        zloss = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
-        return y, {"aux_loss": aux, "z_loss": zloss, "dropped": jnp.mean(1.0 - keep)}
+        zloss = jnp.mean(jax.nn.logsumexp(r.logits, axis=-1) ** 2)
+        return y, {"aux_loss": aux, "z_loss": zloss}
     return y

@@ -287,13 +287,17 @@ def prefill(
     batch: Dict[str, jax.Array],
     cache: Params,
     cfg,
-) -> Tuple[jax.Array, Params]:
-    """Run the prompt, fill caches, return last-position logits."""
+    *,
+    expert_load: bool = False,
+):
+    """Run the prompt, fill caches, return last-position logits and the
+    new caches; with ``expert_load`` also each layer's
+    ``moe.expert_load`` (``[layers, 2]``; a model with experts only)."""
     x = _embed_inputs(params, batch, cfg)
     n_super, per = _superblock_shape(cfg)
 
     def super_prefill(sp, cache_sp, x):
-        new_cache = {}
+        new_cache, loads = {}, []
         for i in range(per):
             p, c = sp[f"l{i}"], cache_sp[f"l{i}"]
             if _mixer_kind(cfg, i, per) == "ssm":
@@ -318,17 +322,21 @@ def prefill(
                     p["attn"], h, cfg, c, window=_layer_window(cfg, i, per)
                 )
                 x = x + y
+            if expert_load:
+                # the same routing the FFN below computes (XLA merges the two)
+                loads.append(moe_mod.expert_load(p["moe"], rmsnorm(x, p["norm2"]), cfg))
             if cfg.family != "ssm":
                 x = _ffn(p, x, cfg)
             new_cache[f"l{i}"] = c2
-        return x, new_cache
+        return x, (new_cache, jnp.stack(loads) if loads else None)
 
     def scan_fn(x, sc):
         sp, cache_sp = sc
-        x, new_c = super_prefill(sp, cache_sp, x)
-        return x, new_c
+        return super_prefill(sp, cache_sp, x)
 
-    x, new_cache = jax.lax.scan(scan_fn, x, (params["blocks"], cache))
+    x, (new_cache, loads) = jax.lax.scan(scan_fn, x, (params["blocks"], cache))
     x = rmsnorm(x[:, -1:], params["final_norm"])
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    if expert_load:
+        return x @ head, new_cache, loads.reshape(cfg.num_layers, 2)
     return x @ head, new_cache

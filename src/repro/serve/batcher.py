@@ -19,8 +19,9 @@ Replaying the same arrival trace reproduces the same outputs.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Deque, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -225,9 +226,22 @@ class ContinuousBatcher:
         self.step_count = 0
         self.results: Dict[int, RequestResult] = {}
         self._submit_step: Dict[int, int] = {}
+        #: per run of the expert layer, newest last: (step, "prefill" or
+        #: "decode", device array ``[layers, 2]``: ``models.moe.load`` per
+        #: layer). Kept on the device, read by step index afterwards; the
+        #: last ``EXPERT_LOAD_KEPT`` runs. Empty for a model without experts
+        self.expert_load: Deque[Tuple[int, str, jax.Array]] = collections.deque(
+            maxlen=self.EXPERT_LOAD_KEPT)
         self.cache = engine.api.cache_init(self.n_slots, engine.max_seq)
         if engine.mesh is not None:
             self.cache = engine._place_cache(self.cache)
+
+    EXPERT_LOAD_KEPT = 4096
+
+    def _log_expert_load(self, kind: str) -> None:
+        load = self.engine.last_expert_load
+        if load is not None:
+            self.expert_load.append((self.step_count, kind, load))
 
     # -- request intake ---------------------------------------------------
     def submit(self, req: Request) -> None:
@@ -259,7 +273,8 @@ class ContinuousBatcher:
         # from the batched compiled decode)
         with TraceAnnotation("prefill", uid=req.uid, prompt_len=len(prompt)):
             one = eng.api.cache_init(1, eng.max_seq)
-            logits, one = eng._prefill(eng.params, {"tokens": prompt[None, :]}, one)
+            logits, one = eng.prefill({"tokens": prompt[None, :]}, one)
+            self._log_expert_load("prefill")
         with TraceAnnotation("first_token", uid=req.uid):
             tok = int(self._sample_one(req.uid, len(prompt) - 1, logits[0, -1]))
 
@@ -474,6 +489,7 @@ class ContinuousBatcher:
             pos = jnp.asarray([s.pos for s in self.slots], jnp.int32)
         with TraceAnnotation("decode"):
             logits, self.cache = self.engine.decode_step(tok, self.cache, pos)
+            self._log_expert_load("decode")
         with TraceAnnotation("sample"):
             sampled = self._sample_batch(
                 np.asarray([s.uid if s.uid is not None else 0 for s in self.slots]),

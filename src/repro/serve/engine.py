@@ -85,10 +85,20 @@ class ServeEngine:
         #: how the last traced decode step bound its matmul weights
         #: (``axe.compile.BindReport``: in place vs copied, bytes per step)
         self.bind_report = None
+        #: the expert layer's load in the last prefill or decode step this
+        #: engine ran: a device array ``[layers, 2]`` (``models.moe.load``),
+        #: never read back here; None for a model without experts
+        self.last_expert_load = None
         self._compiled: Dict[tuple, Any] = {}
         self._warned: set = set()
         self._decode = self._scheduled(jax.jit(self.api.decode_step))
-        self._prefill = self._scheduled(jax.jit(self.api.prefill))
+        if self.api.cfg.is_moe:
+            def prefill(params, batch, cache):
+                return self.api.prefill(params, batch, cache, expert_load=True)
+        else:
+            def prefill(params, batch, cache):
+                return (*self.api.prefill(params, batch, cache), None)
+        self._prefill = self._scheduled(jax.jit(prefill))
 
     @contextlib.contextmanager
     def _dedup_warnings(self):
@@ -230,11 +240,20 @@ class ServeEngine:
         ``cache`` is donated: the caller must drop its reference and use
         the returned one."""
         b = int(tok.shape[0])
-        return self.decode_fn(batch=b)(self.params, cache, tok, pos)
+        logits, cache, self.last_expert_load = self.decode_fn(batch=b)(
+            self.params, cache, tok, pos)
+        return logits, cache
+
+    def prefill(self, batch: Dict[str, jax.Array], cache):
+        """Run the prompts ``batch["tokens"] [B, S]`` through the model,
+        filling ``cache``. Returns ``(logits [B, 1, V], new_cache)``."""
+        logits, cache, self.last_expert_load = self._prefill(self.params, batch, cache)
+        return logits, cache
 
     def decode_fn(self, *, batch: Optional[int] = None):
         """The jitted serving step ``(params, cache, tok, pos) ->
-        (logits, new_cache)`` around :meth:`compiled_decode`. Binding the
+        (logits, new_cache, expert_load)`` around :meth:`compiled_decode`
+        (``expert_load``: ``compile.decode_expert_load``). Binding the
         stacked params and cache onto the graph's per-layer inputs
         (``decode_inputs``/``decode_cache``) happens inside the jit, and
         each matmul weight is bound as a view of the stored param that the
@@ -247,7 +266,8 @@ class ServeEngine:
         ``<kind>/<node>`` from the executable.
         On a mesh the new cache keeps the placed cache's shardings, so the
         next step reuses this executable and the donation aliases."""
-        from repro.axe.compile import bind_report, decode_cache, decode_inputs
+        from repro.axe.compile import (bind_report, decode_cache, decode_expert_load,
+                                       decode_inputs)
 
         key = ("decode_fn", batch or self.batch_size)
         fn = self._compiled.get(key)
@@ -266,7 +286,7 @@ class ServeEngine:
                     if self.mesh is not None:
                         new_cache = jax.lax.with_sharding_constraint(
                             new_cache, self._cache_shardings(cache))
-                return logits, new_cache
+                return logits, new_cache, decode_expert_load(exe.graph, outs)
 
             fn = self._scheduled(jax.jit(step, donate_argnums=(1,)))
             while len(self._compiled) >= self.MAX_COMPILED:
@@ -315,7 +335,7 @@ class ServeEngine:
         batch = {"tokens": prompts}
         if extra_inputs:
             batch.update(extra_inputs)
-        logits, cache = self._prefill(self.params, batch, cache)
+        logits, cache = self.prefill(batch, cache)
 
         key = jax.random.PRNGKey(self.rng_seed)
         outs: List[jax.Array] = []

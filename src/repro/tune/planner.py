@@ -15,6 +15,7 @@ same order (ties broken by the schedule's string form).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -240,19 +241,30 @@ def plan_moe_gemm(
     backend: Optional[str] = None,
     op_name: str = "moe_gemm",
 ) -> List[Candidate]:
-    """Candidates for the per-expert batched GEMM [E,C,d]·[E,d,f]."""
+    """Candidates for the per-expert batched GEMM [E,C,d]·[E,d,f], with
+    ``plan_matmul``'s traffic model per expert: each (C, f) tile
+    re-reads a row-panel of X per f-block and a panel of W per C-block;
+    the XLA batched dot is modeled at its default 128³ tiling. Of
+    tilings that cost the same, the one with fewest grid steps ranks
+    first (each step of a Pallas kernel has a fixed cost)."""
     backend = backend or _backend()
     item = _itemsize(dtype)
     flops = 2.0 * e * c * d * f
     penalty = _kernel_penalty(backend)
 
+    def gemm_bytes(bc: int, bf: int) -> float:
+        x_reads = c * d * max(1, f // bf)
+        w_reads = d * f * max(1, c // bc)
+        return float(e * (x_reads + w_reads + c * f) * item)
+
     out: List[Candidate] = [
-        _mk(Schedule(op_name, "xla"),
-            flops, float(e * (c * d + d * f + c * f) * item), backend=backend)
+        _mk(Schedule(op_name, "xla"), flops, gemm_bytes(min(128, c), min(128, f)),
+            backend=backend)
     ]
+    steps = {out[0].schedule: math.inf}
     for td in candidate_tilings((c, f), dtype, mxu=True):
         bc, bf = td.tile
-        for bd in (512, 256, 128):
+        for bd in (1024, 512, 256, 128):
             if bd > d or d % bd:
                 continue
             if (bc * bd + bd * bf) * item + bc * bf * 4 > 12 * 1024 * 1024:
@@ -262,15 +274,14 @@ def plan_moe_gemm(
                 derive_tiling((d, f), (bd, bf), dtype)
             except Exception:
                 continue
-            x_reads = c * d * max(1, f // bf)
-            w_reads = d * f * max(1, c // bc)
-            mem = float(e * (x_reads + w_reads + c * f) * item)
             cp = penalty if td.mxu_aligned else penalty * 4.0
             sched = Schedule(op_name, "kernel",
                              (("bc", bc), ("bf", bf), ("bd", bd)))
-            out.append(_mk(sched, flops, mem, backend=backend, compute_penalty=cp))
+            out.append(_mk(sched, flops, gemm_bytes(bc, bf), backend=backend,
+                           compute_penalty=cp))
+            steps[sched] = (c // bc) * (f // bf) * (d // bd)
 
-    out.sort(key=lambda c_: (c_.cost_s, c_.schedule.describe()))
+    out.sort(key=lambda c_: (c_.cost_s, steps[c_.schedule], c_.schedule.describe()))
     return out
 
 

@@ -1,6 +1,7 @@
 """The serving path's kernels compile for a TPU v5e chip, with no chip
 attached: ahead-of-time compiles against a described ``v5e:2x2``
-topology at qwen3-4b's published widths (bf16), ``interpret=False`` and
+topology at qwen3-4b's and qwen3-moe-235b-a22b's published widths
+(bf16), ``interpret=False`` and
 the chip's own schedule choices. Each compile must hold a Pallas kernel
 (``tpu_custom_call``) — interpret mode cannot show a block shape or a
 scalar the chip's compiler refuses.
@@ -169,3 +170,53 @@ def test_serving_decode_step_compiles(one_chip, as_chip):
     # per layer: 7 matmuls, 2 norms, 1 flash decode; plus final norm + lm_head
     assert _kernels(compiled) == 2 * 10 + 2
     assert "flash_attention_decode" in text
+
+
+E_HELD, D_MOE, F_MOE = 8, 4096, 1536   # qwen3-moe-235b-a22b: one chip's 8 of 128 experts
+
+
+@pytest.mark.parametrize("rows", [64, 256])     # decode step's slots, largest prefill bucket
+@pytest.mark.parametrize("k,n", [(D_MOE, F_MOE), (F_MOE, D_MOE)])
+def test_moe_gemm_reads_a_layer_of_the_expert_stack(one_chip, as_chip, rows, k, n):
+    """The expert kernel reads one layer of the stacked expert weight
+    ``[L, E, k, n]`` where it lies: one kernel, no slice or copy."""
+    from repro.kernels import programs
+    from repro.kernels.moe_gemm import ExpertView
+
+    x = _sds((E_HELD, rows, k), jnp.bfloat16, one_chip)
+    w = _sds((16, E_HELD, k, n), jnp.bfloat16, one_chip)
+    with scope(Scope.DEVICE):
+        compiled = jax.jit(
+            lambda x, w: programs.moe_gemm(x, ExpertView(w, 3))).lower(x, w).compile()
+    text = compiled.as_text()
+    assert _kernels(compiled) == 1 and "moe_gemm_expert_gemm" in text
+    assert " copy(" not in text and "slice" not in text
+
+
+def test_moe_serving_decode_step_reads_experts_in_place(one_chip, as_chip):
+    """A two-layer cut of qwen3-moe-235b-a22b's serving step at published
+    widths, 8 experts held of 128, 64 rows: every expert product is the
+    Pallas kernel reading the stacked leaf, and no weight is copied."""
+    from repro.configs import get_config
+    from repro.models.model_zoo import build_model
+    from repro.serve.engine import ServeEngine
+
+    cfg = dataclasses.replace(get_config("qwen3-moe-235b-a22b"), num_layers=2,
+                              experts_held=E_HELD)
+    api = build_model(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip), tree)
+
+    params = on_chip(jax.eval_shape(api.init, jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(lambda: api.cache_init(64, MAX_SEQ)))
+    tok = _sds((64,), jnp.int32, one_chip)
+    eng = ServeEngine(api, batch_size=64, max_seq=MAX_SEQ)
+    compiled = eng.decode_fn().lower(params, cache, tok, tok).compile()
+    text = compiled.as_text()
+    experts = [ln for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln and "moe_gemm_expert_gemm" in ln]
+    assert len(experts) == 3 * 2
+    assert all("params__blocks____l0____moe____w" in ln for ln in experts)
+    report = eng.bind_report
+    assert report.copied == 0 and report.in_place == 2 * (4 + 3) + 1

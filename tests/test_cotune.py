@@ -14,7 +14,6 @@ decision — the whole point of closing the loop. (4) The service:
 artifact merging is associative / commutative / idempotent and corrupt
 entries are quarantined, never fatal.
 """
-import dataclasses
 import json
 
 import pytest
@@ -41,8 +40,6 @@ _SPACE = PhysicalSpace.from_mesh_shape({"data": 16, "model": 16})
 
 def _cfg(arch):
     cfg = smoke_variant(get_config(arch))
-    if cfg.is_moe:
-        cfg = dataclasses.replace(cfg, capacity_factor=float(cfg.num_experts))
     return cfg
 
 

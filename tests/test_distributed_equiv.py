@@ -25,7 +25,6 @@ mesh = compat.make_mesh((2, 4), ("data", "model"))
 # --- 1. expert-parallel MoE vs local path -------------------------------
 cfg = dataclasses.replace(
     smoke_variant(get_config("dbrx-132b")), num_experts=8, experts_per_tok=2,
-    capacity_factor=8.0,  # no drops -> paths must agree exactly
 )
 p = moe_mod.moe_init(jax.random.PRNGKey(0), cfg, jnp.float32)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model), jnp.float32)
@@ -36,12 +35,9 @@ with act_sharding.mesh_context(mesh), mesh:
     y_ep = jax.jit(lambda p, x: moe_mod.moe_apply(p, x, cfg))(p, x)
 err_moe = float(jnp.max(jnp.abs(y_local - y_ep)))
 
-# NOTE: with capacity drops the paths can differ (drop sets differ by
-# shard) — checked only in the no-drop regime, which is the invariant.
-
 # --- 2. full train-loss forward, sharded vs unsharded -------------------
 cfg2 = smoke_variant(get_config("qwen3-moe-235b-a22b"))
-cfg2 = dataclasses.replace(cfg2, num_experts=8, capacity_factor=8.0)
+cfg2 = dataclasses.replace(cfg2, num_experts=8)
 api = build_model(cfg2)
 params = api.init(jax.random.PRNGKey(0))
 batch = api.make_train_batch(jax.random.PRNGKey(1), ShapeSpec("s", "train", 64, 4))

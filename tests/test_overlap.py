@@ -50,8 +50,6 @@ _SPACE = PhysicalSpace.from_mesh_shape({"data": 2, "model": 4})
 
 def _cfg(arch, dtype=None):
     cfg = smoke_variant(get_config(arch))
-    if cfg.is_moe:
-        cfg = dataclasses.replace(cfg, capacity_factor=float(cfg.num_experts))
     if dtype is not None:
         cfg = dataclasses.replace(cfg, dtype=dtype)
     return cfg
@@ -337,8 +335,6 @@ from repro.models.model_zoo import build_model
 
 def cfg_for(arch, dtype=None):
     cfg = smoke_variant(get_config(arch))
-    if cfg.is_moe:
-        cfg = dataclasses.replace(cfg, capacity_factor=float(cfg.num_experts))
     if dtype is not None:
         cfg = dataclasses.replace(cfg, dtype=dtype)
     return cfg

@@ -10,7 +10,6 @@ Fused executables inherit the unfused solve's layout assignment
 (``axe.compile`` transfer semantics), so parity here is bit-exact, not
 merely within tolerance.
 """
-import dataclasses
 import warnings
 
 import jax
@@ -39,9 +38,6 @@ ARCHS = (
 
 def _cfg(arch):
     cfg = smoke_variant(get_config(arch))
-    if cfg.is_moe:
-        # drop-free capacity: local and global routing agree exactly
-        cfg = dataclasses.replace(cfg, capacity_factor=float(cfg.num_experts))
     return cfg
 
 

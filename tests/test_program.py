@@ -144,29 +144,19 @@ def test_collective_matmul_program_parity_both_dtypes():
 def test_moe_routing_matches_loop_oracle():
     from repro.models import moe as moe_mod
 
-    class Cfg:
-        num_experts = 4
-        experts_per_tok = 2
-        capacity_factor = 1.25
-        moe_d_ff = 64
-        d_model = 32
+    e, k, t, d = 4, 2, 64, 32
+    xf = jax.random.normal(jax.random.PRNGKey(0), (t, d), jnp.float32)
+    router = jax.random.normal(jax.random.PRNGKey(1), (d, e), jnp.float32)
 
-    cfg = Cfg()
-    t, d = 64, cfg.d_model
-    key = jax.random.PRNGKey(0)
-    xf = jax.random.normal(key, (t, d), jnp.float32)
-    router = jax.random.normal(jax.random.PRNGKey(1), (d, cfg.num_experts), jnp.float32)
-
-    buf, meta = moe_mod._local_dispatch(xf, router, cfg)
-    c = moe_mod.capacity(t, cfg)
+    r = moe_mod.route(xf, router, experts_per_tok=k, held=e)
+    buf = moe_mod.dispatch(xf, r.routed)
     ref_buf, ref_combine = ref.moe_routing_ref(
-        np.asarray(xf), np.asarray(router),
-        experts_per_tok=cfg.experts_per_tok, capacity=c,
+        np.asarray(xf), np.asarray(router), experts_per_tok=k,
     )
     np.testing.assert_allclose(np.asarray(buf), ref_buf, rtol=1e-5, atol=1e-5)
 
     # identity "FFN": combine must gate-weight and gather identically
-    y = moe_mod._local_combine(buf, meta, t, d)
+    y = moe_mod.combine(buf, r.gates)
     np.testing.assert_allclose(np.asarray(y), ref_combine(ref_buf), rtol=1e-5, atol=1e-5)
 
 

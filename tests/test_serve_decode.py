@@ -39,9 +39,6 @@ B, MAX_SEQ, S0 = 2, 32, 5
 
 def _cfg(arch, dtype="float32"):
     cfg = smoke_variant(get_config(arch))
-    if cfg.is_moe:
-        # drop-free capacity: local and global routing agree exactly
-        cfg = dataclasses.replace(cfg, capacity_factor=float(cfg.num_experts))
     return dataclasses.replace(cfg, dtype=dtype)
 
 
@@ -211,11 +208,13 @@ def test_decode_step_per_slot_positions():
 # weights bound in place: decode_inputs' views vs plain per-layer slices
 # ---------------------------------------------------------------------------
 
-#: every product in the matmul kernel (interpret mode), as on the chip
-KERNELS = {"matmul/tile": "kernel"}
+#: every product in the matmul or expert kernel (interpret mode), as on the chip
+KERNELS = {"matmul/tile": "kernel", "moe_gemm/expert_gemm": "kernel"}
 #: the benchmark cells' architectures at smoke size, heads tied as there:
-#: qk-norm + GQA, and the SSD mixer; their matmul weights (2 layers + head)
-BOUND_WEIGHTS = {"qwen3-4b": 2 * 7 + 1, "mamba2-2.7b": 2 * 6 + 1}
+#: qk-norm + GQA, the SSD mixer, and the expert layer; their matmul
+#: weights (2 layers + head)
+BOUND_WEIGHTS = {"qwen3-4b": 2 * 7 + 1, "mamba2-2.7b": 2 * 6 + 1,
+                 "qwen3-moe-235b-a22b": 2 * (4 + 3) + 1}
 
 
 def _tied_setup(arch):
@@ -486,8 +485,6 @@ for arch in ("qwen3-4b", "qwen3-moe-235b-a22b", "mamba2-2.7b",
              "jamba-1.5-large-398b"):
     cfg = dataclasses.replace(smoke_variant(get_config(arch)),
                               dtype="float32")
-    if cfg.is_moe:
-        cfg = dataclasses.replace(cfg, capacity_factor=float(cfg.num_experts))
     api = build_model(cfg)
     params = api.init(jax.random.PRNGKey(0))
     b, max_seq, s0 = 4, 32, 5
