@@ -547,10 +547,14 @@ class LoweredOp:
     #: operands whose collectives the overlap schedule issues one entry
     #: early, hiding them under the previous op's compute (docs/overlap.md)
     prefetched: Tuple[str, ...] = ()
+    #: the planned schedule's grid steps (``tune.planner``), where it counts them
+    grid_steps: Optional[int] = None
 
     def describe(self) -> str:
         cols = "; ".join(f"{o}:{'+'.join(s)}" for o, s in self.collectives)
         sched = f"  sched={self.schedule}" if self.schedule else ""
+        if self.grid_steps is not None:
+            sched += f" steps={self.grid_steps}"
         comm = f"  comm={self.comm_bytes}B" if self.comm_bytes else ""
         pre = (f"  prefetch=[{', '.join(self.prefetched)}]"
                if self.prefetched else "")
@@ -678,7 +682,7 @@ class Executable:
         from repro.tune import planner as tune_planner
 
         node = entry.op
-        sched = None
+        sched = steps = None
         in_specs: Tuple[AxeSpec, ...] = ()
         if node.kind != "finalize":
             # plan schedules from the POST-redistribution specs — the
@@ -688,6 +692,7 @@ class Executable:
             sp = tune_planner.plan_from_specs(node.kind, in_specs, backend=None)
             if sp is not None and sp.schedule is not None:
                 sched = f"{sp.op}={sp.schedule.describe()}"
+                steps = sp.candidates[0].terms_dict.get("grid_steps")
         return LoweredOp(
             op=node.name,
             kind=node.kind,
@@ -702,6 +707,7 @@ class Executable:
             prefetched=tuple(
                 op for (j, op) in sorted(self._hoisted) if j == idx
             ),
+            grid_steps=steps,
         )
 
     def collective_sequence(self) -> Tuple[Tuple[str, str, Tuple[str, ...]], ...]:

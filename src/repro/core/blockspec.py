@@ -19,6 +19,7 @@ kernel wrappers validate their tilings.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Sequence, Tuple
 
@@ -78,17 +79,24 @@ def derive_tiling(shape: Sequence[int], tile: Sequence[int], dtype=jnp.float32) 
         acc *= s
     full_strides.reverse()
 
-    grid_strides = tuple(t * st for t, st in zip(tile, full_strides))
-    A = strided(grid, grid_strides)           # tile origins
-    B = strided(tile, tuple(full_strides))    # strided HBM box
-    T, _ = direct_sum(A, grid, B, tile)
-    if not layouts_equal(T, from_shape(shape)):
+    if not _direct_sum_ok(shape, tile, grid, tuple(full_strides)):
         raise TilingError(f"direct-sum decomposition failed for {shape} / {tile}")
 
     sub, lane = vreg_atom(dtype)
     vreg_ok = len(tile) >= 2 and tile[-1] % lane == 0 and tile[-2] % sub == 0
     mxu_ok = len(tile) >= 2 and tile[-1] % MXU_TILE[1] == 0 and tile[-2] % MXU_TILE[0] == 0
     return TileDerivation(shape, tile, grid, tuple(full_strides), vreg_ok, mxu_ok)
+
+
+@functools.lru_cache(maxsize=4096)
+def _direct_sum_ok(shape, tile, grid, full_strides) -> bool:
+    """``dense(shape) == Grid + Box``, memoised: the planner asks it of the
+    same tiles for every layer of a model."""
+    grid_strides = tuple(t * st for t, st in zip(tile, full_strides))
+    A = strided(grid, grid_strides)           # tile origins
+    B = strided(tile, full_strides)           # strided HBM box
+    T, _ = direct_sum(A, grid, B, tile)
+    return layouts_equal(T, from_shape(shape))
 
 
 def nearest_valid_tile(
@@ -223,6 +231,32 @@ def candidate_tilings(
             except TilingError:
                 continue
     return tuple(out)
+
+
+#: VMEM one tiled-matmul grid step may hold: under Mosaic's default
+#: 16 MiB of scoped VMEM on a v5e, so no compiler parameter changes
+MATMUL_VMEM_BYTES = 12 * 1024 * 1024
+
+
+def matmul_vmem_bytes(bm: int, bn: int, bk: int, dtype, *, c_tiles: int = 1) -> int:
+    """VMEM one grid step of ``C = A @ B`` holds: the double-buffered A
+    ``(bm, bk)`` and B ``(bk, bn)`` tiles, ``c_tiles`` double-buffered
+    ``(bm, bn)`` tiles (C and any operand tiled like it) and the f32
+    accumulator."""
+    item = jnp.dtype(dtype).itemsize
+    return 2 * (bm * bk + bk * bn + c_tiles * bm * bn) * item + bm * bn * 4
+
+
+def matmul_k_blocks(k: int, bm: int, bn: int, dtype, *, c_tiles: int = 1) -> Tuple[int, ...]:
+    """The K blocks a tiled matmul with ``(bm, bn)`` tiles may take,
+    largest first: every multiple of the lane width that divides K, up
+    to K itself, whose grid step fits :data:`MATMUL_VMEM_BYTES`."""
+    lane = MXU_TILE[1]
+    return tuple(
+        bk for bk in range(k - k % lane, 0, -lane)
+        if k % bk == 0
+        and matmul_vmem_bytes(bm, bn, bk, dtype, c_tiles=c_tiles) <= MATMUL_VMEM_BYTES
+    )
 
 
 def pick_tile(

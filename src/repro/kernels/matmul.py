@@ -37,7 +37,10 @@ import jax.experimental.pallas.tpu as pltpu
 
 from repro.axe.lower import block_lowering
 from repro.axe.program import KernelFallbackWarning, program
-from repro.core.blockspec import TilingError, check_tiling, vreg_atom
+from repro.core.blockspec import (
+    MATMUL_VMEM_BYTES, TilingError, check_tiling, matmul_k_blocks, matmul_vmem_bytes,
+    vreg_atom,
+)
 from repro.core.scopes import Scope
 
 
@@ -235,6 +238,11 @@ def _tile(ctx, a, b, *, out_dtype=None):
     if view is not None:
         bn = view.block_n(bn)
     bk = min(ctx.block("bk"), k)
+    n_extras = len(epi.args) if inline else 0
+    if matmul_vmem_bytes(bm, bn, bk, a.dtype, c_tiles=1 + n_extras) > MATMUL_VMEM_BYTES:
+        # a column block widened past the planned one (whole heads, whole
+        # rows) takes the largest K block that still fits beside it
+        bk = next(iter(matmul_k_blocks(k, bm, bn, a.dtype, c_tiles=1 + n_extras)), bk)
     try:
         # fail fast on infeasible output tiles (same precheck the legacy
         # dispatch made); A/B tilings are re-validated inside the launch
@@ -242,7 +250,6 @@ def _tile(ctx, a, b, *, out_dtype=None):
     except TilingError as exc:
         return fallback(exc)
 
-    n_extras = len(epi.args) if inline else 0
     # everything the cached launcher reads besides its arguments is fixed
     # by its key: never the first call's view or layer
     is_view = view is not None

@@ -246,6 +246,9 @@ def solve_cell(
                 "layout_sig_len": len(sp.layout_sig),
                 "schedule": sp.schedule.describe(),
             }
+            steps = sp.candidates[0].terms_dict.get("grid_steps")
+            if steps is not None:
+                schedules[e.op.name]["grid_steps"] = steps
     record["schedules"] = schedules
     record["status"] = "ok"
     if verbose:

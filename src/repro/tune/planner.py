@@ -7,7 +7,9 @@ the ordered list of schedules the dispatch *could* run, each one
 Axe-validated (``core.blockspec.derive_tiling`` — candidates whose grid
 cells are not strided HBM boxes never appear). Ranking is analytic
 (``launch.roofline.schedule_time``); the autotuner refines the top of
-the list empirically.
+the list empirically. On the TPU a tiled matmul also pays
+:data:`GRID_STEP_S` per grid step, so its blocks are sized by step
+count as well as by HBM traffic.
 
 Enumeration is deterministic: same inputs → same candidate list in the
 same order (ties broken by the schedule's string form).
@@ -21,13 +23,30 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.blockspec import candidate_tilings, derive_tiling, vreg_atom
+from repro.core.blockspec import (
+    MXU_TILE, candidate_blocks, candidate_tilings, derive_tiling, matmul_k_blocks,
+    vreg_atom,
+)
 from repro.launch import roofline
 from repro.tune.schedule import Schedule
 
 #: interpreted Pallas kernels are only worth *measuring* off-TPU below
 #: this op size (the autotuner would otherwise spend minutes per shape)
 INTERPRET_MEASURE_FLOPS = 2 * 256**3 * 4
+
+#: per-chunk-step dispatch overhead (s) — XLA launch + mask/softmax
+#: epilogue per block; makes small chunks rank worse, as measured
+MHA_CHUNK_OVERHEAD_S = 5e-6
+
+#: fixed cost (s) of one grid step of a tiled matmul on a TPU v5e, on top
+#: of its bytes. Kernel alone at 16 rows with 128-wide K blocks: qwen3-4b's
+#: tied head 7.03 ms over 23,740 steps (0.30 us each, least time 0.95 ms),
+#: the MLP gate 5.14 ms over 13,680 steps (0.38 us, least 2.19 ms)
+GRID_STEP_S = 0.35e-6
+
+#: column blocks ``plan_matmul`` offers: wide enough that a decode weight
+#: product reads a few large tiles rather than thousands of small ones
+MATMUL_BN_PREFER = (2048, 1024, 512, 256, 128)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -52,11 +71,18 @@ def _itemsize(dtype) -> int:
 
 
 def _mk(schedule: Schedule, flops: float, mem_bytes: float, *,
-        backend: str, comm_bytes: float = 0.0, compute_penalty: float = 1.0) -> Candidate:
+        backend: str, comm_bytes: float = 0.0, compute_penalty: float = 1.0,
+        grid_steps: Optional[int] = None) -> Candidate:
+    """A candidate priced by the roofline; ``grid_steps`` adds the count
+    to its terms and, on the TPU, :data:`GRID_STEP_S` per step to its cost."""
     cost, terms = roofline.schedule_time(
         flops=flops, mem_bytes=mem_bytes, comm_bytes=comm_bytes,
         backend=backend, compute_penalty=compute_penalty,
     )
+    if grid_steps is not None:
+        terms["grid_steps"] = grid_steps
+        if backend == "tpu":
+            cost += grid_steps * GRID_STEP_S
     return Candidate(schedule, cost, tuple(sorted(terms.items())))
 
 
@@ -82,23 +108,33 @@ def plan_matmul(
     Kernel traffic model (per §3.4 tiling): each (i, j) output tile
     re-reads a row-panel of A per N-block and a column-panel of B per
     M-block, so HBM bytes fall as the tiles grow — exactly what the
-    autotuner observes on TPU. The XLA dot is modeled at its default
-    128³ tiling. Off-TPU, kernels carry the interpret-mode penalty so
-    the compiled XLA schedule always ranks first.
+    autotuner observes on TPU. Each candidate also counts its grid steps
+    (``grid_steps`` in its terms), which the TPU charges
+    :data:`GRID_STEP_S` each: K blocks grow up to the whole K and column
+    blocks to :data:`MATMUL_BN_PREFER` while the step fits VMEM
+    (``core.blockspec.matmul_k_blocks``); row blocks keep
+    ``candidate_tilings``' default sizes. The XLA dot is modeled at its
+    default 128³ tiling, steps included. Off-TPU, kernels carry the
+    interpret-mode penalty so the compiled XLA schedule always ranks
+    first.
     """
     backend = backend or _backend()
     item = _itemsize(dtype)
     flops = 2.0 * m * k * n
 
-    def gemm_bytes(bm: int, bn: int, bk: int) -> float:
+    def gemm_bytes(bm: int, bn: int) -> float:
         a_reads = m * k * max(1, n // bn)
         b_reads = k * n * max(1, m // bm)
         return float((a_reads + b_reads + m * n) * item)
 
+    def grid_steps(bm: int, bn: int, bk: int) -> int:
+        return -(-m // bm) * -(-n // bn) * -(-k // bk)
+
     out: List[Candidate] = []
 
     # XLA dot candidate (always valid — no divisibility constraints)
-    xla_bytes = gemm_bytes(min(128, m), min(128, n), min(128, k))
+    xla_tile = (min(128, m), min(128, n), min(128, k))
+    xla_bytes = gemm_bytes(*xla_tile[:2])
     if use_hlo:
         try:
             from repro.launch import hlo_cost
@@ -109,18 +145,17 @@ def plan_matmul(
             xla_bytes = c.bytes or xla_bytes
         except Exception:
             pass
-    out.append(_mk(Schedule(op_name, "xla"), flops, xla_bytes, backend=backend))
+    out.append(_mk(Schedule(op_name, "xla"), flops, xla_bytes, backend=backend,
+                   grid_steps=grid_steps(*xla_tile)))
 
     # Pallas kernel candidates: Axe-validated (M,N) tilings × K blocks
     penalty = _kernel_penalty(backend)
-    for d in candidate_tilings((m, n), dtype, mxu=True):
+    row_blocks = candidate_blocks(m, minimum=MXU_TILE[0])
+    for d in candidate_tilings((m, n), dtype, mxu=True, prefer=MATMUL_BN_PREFER):
         bm, bn = d.tile
-        for bk in (512, 256, 128):
-            if bk > k or k % bk:
-                continue
-            # VMEM residency: A tile + B tile + f32 accumulator
-            if (bm * bk + bk * bn) * item + bm * bn * 4 > 12 * 1024 * 1024:
-                continue
+        if bm not in row_blocks:
+            continue
+        for bk in matmul_k_blocks(k, bm, bn, dtype):
             try:
                 derive_tiling((m, k), (bm, bk), dtype)
                 derive_tiling((k, n), (bk, bn), dtype)
@@ -129,8 +164,8 @@ def plan_matmul(
             sched = Schedule(op_name, "kernel",
                              (("bm", bm), ("bn", bn), ("bk", bk)))
             cp = penalty if d.mxu_aligned else penalty * 4.0
-            out.append(_mk(sched, flops, gemm_bytes(bm, bn, bk),
-                           backend=backend, compute_penalty=cp))
+            out.append(_mk(sched, flops, gemm_bytes(bm, bn), backend=backend,
+                           compute_penalty=cp, grid_steps=grid_steps(bm, bn, bk)))
 
     out.sort(key=lambda c: (c.cost_s, c.schedule.describe()))
     return out
@@ -187,10 +222,6 @@ def plan_flash_attention(
 # ---------------------------------------------------------------------------
 # blocked-softmax attention at MESH scope: the XLA chunk size
 # ---------------------------------------------------------------------------
-
-#: per-chunk-step dispatch overhead (s) — XLA launch + mask/softmax
-#: epilogue per block; makes small chunks rank worse, as measured
-MHA_CHUNK_OVERHEAD_S = 5e-6
 
 
 def plan_mha_blocked(

@@ -172,6 +172,21 @@ def test_lowering_trace_stage_keyed_schedules():
     assert any(s.startswith("rmsnorm/rows=") for s in scheds), scheds
 
 
+def test_lowering_trace_counts_matmul_grid_steps(monkeypatch):
+    """Each planned ``matmul/tile`` row carries its schedule's grid steps,
+    as the planner ranks it for the chip, and prints them."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = _cfg("qwen3-4b")
+    space = axe.PhysicalSpace.from_mesh_shape({"data": 1})
+    gs = axe.model_graph(cfg, 16, 1, space, dtype=cfg.dtype, layers=1)
+    rows = [r for r in axe.compile(gs, None).lowering_trace
+            if r.schedule and r.schedule.startswith("matmul/tile=kernel")]
+    assert rows
+    for r in rows:
+        assert r.grid_steps >= 1
+        assert f"steps={r.grid_steps}" in r.describe()
+
+
 def test_compile_accepts_solve_result_and_mapping():
     cfg = _cfg("qwen3-4b")
     space = axe.PhysicalSpace.from_mesh_shape({"data": 2, "model": 4})

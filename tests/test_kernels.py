@@ -75,6 +75,51 @@ def test_matmul_reads_weight_views(dtype, stored, layer, form):
     )
 
 
+def _pallas_grids(fn, *args):
+    """The grid of every Pallas launch ``fn`` traces."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield tuple(eqn.params["grid_mapping"].grid)
+            for p in eqn.params.values():
+                if hasattr(p, "jaxpr") and hasattr(p.jaxpr, "eqns"):
+                    yield from walk(p.jaxpr)
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+@pytest.mark.parametrize(
+    "stored,layer,form,bk,grid",
+    [
+        # bk = K: one grid step per column block
+        ((3, 1024, 384), 1, "layer", 1024, (1, 3, 1)),
+        ((640, 1024), None, "transposed", 1024, (1, 5, 1)),     # a tied head
+        # 16 whole heads widen bn 128 → 2,048; beside them a 2,048-deep
+        # tile would need 17 MB of VMEM, so the kernel halves bk
+        ((2, 2048, 16, 128), 1, "heads", 2048, (1, 1, 2)),
+    ],
+)
+def test_matmul_reads_views_in_whole_k_blocks(stored, layer, form, bk, grid):
+    """Large K blocks on each form of weight view give the exact product
+    of the materialised weight (small integers: every sum is exact)."""
+    from repro.kernels.matmul import WeightView
+
+    k1, k2 = jax.random.split(jax.random.PRNGKey(4))
+    w = jax.random.randint(k2, stored, -2, 3).astype(jnp.bfloat16)
+    view = {"layer": lambda: WeightView.of_layer(w, layer),
+            "heads": lambda: WeightView(w, layer),
+            "transposed": lambda: WeightView(w, transposed=True)}[form]()
+    a = jax.random.randint(k1, (16, view.shape[0]), -2, 3).astype(jnp.bfloat16)
+
+    def product(a, v):
+        return programs.matmul(a, v, stage="tile", impl="kernel", out_dtype=jnp.float32,
+                               blocks={"bm": 16, "bn": 128, "bk": bk})
+
+    assert _pallas_grids(product, a, view) == [grid]
+    got = jax.jit(product)(a, view)
+    want = jnp.dot(a, view.materialize(), preferred_element_type=jnp.float32)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 # ---------------------------------------------------------------------------
 # flash attention
 # ---------------------------------------------------------------------------

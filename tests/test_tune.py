@@ -122,6 +122,77 @@ def test_cpu_ranking_prefers_compiled_xla():
     assert best.schedule.impl == "xla"
 
 
+def _plan_bf16(m, k, n, backend="tpu"):
+    return planner.plan("matmul/tile", shapes=((m, k), (k, n)),
+                        dtypes=(jnp.bfloat16, jnp.bfloat16), backend=backend)
+
+
+@pytest.mark.parametrize("m,k", [(16, 2560), (64, 4096)])   # qwen3-4b, the MoE cell
+def test_tpu_head_reads_whole_k_blocks(m, k):
+    """A vocabulary head (151,936 = 128 · 1,187, 1,187 prime) takes
+    ``bk`` = K: one grid step per 128-column block, and the XLA dot, at
+    its 128³ tiling, ranks below it."""
+    cands = _plan_bf16(m, k, 151936)
+    best = cands[0]
+    assert best.schedule.impl == "kernel"
+    assert best.schedule.block("bk") == k
+    assert best.terms_dict["grid_steps"] == 1187
+    xla = next(c for c in cands if c.schedule.impl == "xla")
+    assert xla.terms_dict["grid_steps"] > best.terms_dict["grid_steps"]
+    assert xla.cost_s > best.cost_s
+
+
+def test_tpu_mlp_weight_takes_few_grid_steps():
+    best = _plan_bf16(16, 2560, 9728)[0]     # qwen3-4b's gate/up at 16 rows
+    assert best.schedule.impl == "kernel"
+    assert best.terms_dict["grid_steps"] <= 20
+
+
+@pytest.mark.parametrize("m,k,n", [
+    (16, 2560, 151936), (64, 4096, 151936), (16, 2560, 9728), (16, 9728, 2560),
+    (64, 4096, 8192), (512, 2560, 9728), (2048, 1024, 1536),
+])
+def test_kernel_candidates_fit_vmem_budget(m, k, n):
+    from repro.core.blockspec import MATMUL_VMEM_BYTES, matmul_vmem_bytes
+
+    kernels = [c for c in _plan_bf16(m, k, n) if c.schedule.impl == "kernel"]
+    assert kernels
+    for c in kernels:
+        bm, bn, bk = (c.schedule.block(x) for x in ("bm", "bn", "bk"))
+        assert matmul_vmem_bytes(bm, bn, bk, jnp.bfloat16) <= MATMUL_VMEM_BYTES
+        assert c.terms_dict["grid_steps"] == (m // bm) * (n // bn) * (k // bk)
+
+
+@pytest.mark.parametrize("m,k,n", [(16, 2560, 151936), (16, 9728, 2560), (64, 4096, 8192)])
+def test_grid_step_ranking_deterministic(m, k, n):
+    a, b = _plan_bf16(m, k, n), _plan_bf16(m, k, n)
+    assert [(c.schedule, c.cost_s, c.terms) for c in a] == \
+        [(c.schedule, c.cost_s, c.terms) for c in b]
+    assert a == sorted(a, key=lambda c: (c.cost_s, c.schedule.describe()))
+
+
+@pytest.mark.parametrize("m,k,n", [(16, 2560, 151936), (16, 2560, 9728)])
+def test_cpu_decode_weights_still_rank_xla_first(m, k, n):
+    """Off the TPU the step count is reported, never charged: the
+    interpret penalty keeps the XLA dot first."""
+    best = _plan_bf16(m, k, n, backend="cpu")[0]
+    assert best.schedule.impl == "xla"
+    terms = best.terms_dict
+    assert best.cost_s == max(terms["compute"], terms["memory"], terms["collective"])
+
+
+@pytest.mark.parametrize("x_shape,w_shape,want", [
+    ((8, 64, 4096), (8, 4096, 1536), "kernel:bc=64,bd=1024,bf=512"),  # gate/up
+    ((8, 64, 1536), (8, 1536, 4096), "kernel:bc=64,bd=512,bf=512"),   # down
+])
+def test_moe_gemm_plan_unchanged_for_expert_shapes(x_shape, w_shape, want):
+    """The grouped expert product keeps its own ranking: the MoE cell's
+    expert shapes (8 held experts, 64 slots) plan as before."""
+    best = planner.plan("moe_gemm/expert_gemm", shapes=(x_shape, w_shape),
+                        dtypes=(jnp.bfloat16,), backend="tpu")[0]
+    assert best.schedule.describe() == want
+
+
 def test_plan_all_ops():
     assert planner.plan("flash_attention", shapes=((1, 2, 256, 64), (1, 2, 256, 64)),
                         dtypes=(jnp.float32,))
