@@ -41,7 +41,7 @@ from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.axe.graphs import GraphSpec
+from repro.axe.graphs import GraphSpec, Stored
 from repro.axe.propagate import (
     LayoutPlan,
     OpNode,
@@ -1159,80 +1159,86 @@ def compile(  # noqa: A001 - the paper-facing API name
 SUPPORTED_FAMILIES = ("dense", "moe", "ssm", "hybrid")
 
 
-def _period(cfg) -> int:
-    if cfg.local_global_ratio:
-        return cfg.local_global_ratio + 1
-    if cfg.attn_period:
-        return cfg.attn_period
-    return 1
+def _plain(r: Stored, a, meta):
+    """The entry ``a`` of a stored leaf in the graph input's shape."""
+    if r.view == "T":
+        return a.T
+    if r.view in ("cols", "rows"):
+        return a.reshape(meta.shape)
+    return a
 
 
-def _graph_layers(graph: GraphSpec) -> List[int]:
-    seen = set()
-    for node in graph.nodes:
-        if node.name.startswith("L") and "." in node.name:
-            head = node.name[1:].split(".", 1)[0]
-            if head.isdigit():
-                seen.add(int(head))
-    return sorted(seen)
+def _in_place(r: Stored, leaf, meta):
+    """The stored ``leaf`` as a view the ``matmul/tile`` or
+    ``moe_gemm/expert_gemm`` kernel reads where it lies."""
+    from repro.kernels.matmul import WeightView
+    from repro.kernels.moe_gemm import ExpertView
+
+    if r.view == "T":
+        return WeightView(leaf, r.block, transposed=True)
+    if r.block is None:
+        return _plain(r, leaf, meta)
+    if r.view == "experts":
+        return ExpertView(leaf, r.block)
+    if r.view == "cols":
+        return WeightView(leaf, r.block)
+    if r.view == "rows":
+        # [L, H, hd, d] -> [L, H·hd, d] merges whole tiles: a bitcast
+        leaf = leaf.reshape((-1, *meta.shape))
+    return WeightView.of_layer(leaf, r.block)
 
 
-def model_inputs(graph: GraphSpec, cfg, params) -> Dict[str, Any]:
-    """Map a reference model param pytree (``models.transformer``
-    layout: scanned super-blocks) onto the graph's input tensors and
-    auxiliary names, reshaping per-head projections onto the graph's
-    2-D views (``wq [d, H, hd] → [d, H·hd]`` — head-major columns, so a
-    solved column sharding is a head sharding of the model leaf)."""
+def _bind(graph: GraphSpec, cfg, params, cache=None, *, views: bool = False):
+    """Read every stored tensor the graph names (its ``param``/``cache``
+    inputs and its auxiliary tensors) out of the stored pytrees, where
+    its record (:class:`~repro.axe.graphs.Stored`) says. With ``views``
+    each weight only matmuls read is an in-place view. Every other param
+    is a plain array: per super-block, each leaf's entry sliced in key
+    order, then shaped in graph order — the order a per-layer
+    ``jax.tree.map`` over the stored params gives. Cache entries follow,
+    in graph order."""
     if cfg.family not in SUPPORTED_FAMILIES:
         raise CompileError(
             f"family {cfg.family!r} has no model binding "
             f"(supported: {SUPPORTED_FAMILIES})"
         )
-    d = cfg.d_model
-    per = _period(cfg)
-    out: Dict[str, Any] = {
-        "embed": params["embed"],
-        "final_norm": params["final_norm"],
-        "lm_head": params["embed"].T if cfg.tie_embeddings else params["lm_head"],
-    }
-    for i in _graph_layers(graph):
-        sup, slot = i // per, i % per
-        lp = jax.tree.map(lambda a: a[sup], params["blocks"][f"l{slot}"])
-        p = f"L{i}."
-        out[f"{p}norm1"] = lp["norm1"]
-        if "attn" in lp:
-            ap = lp["attn"]
-            h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-            out[f"{p}wq"] = ap["wq"].reshape(d, h * hd)
-            out[f"{p}wk"] = ap["wk"].reshape(d, kv * hd)
-            out[f"{p}wv"] = ap["wv"].reshape(d, kv * hd)
-            out[f"{p}wo"] = ap["wo"].reshape(h * hd, d)
-            if cfg.qk_norm:
-                out[f"{p}q_norm"] = ap["q_norm"]
-                out[f"{p}k_norm"] = ap["k_norm"]
-        if "ssm" in lp:
-            sp = lp["ssm"]
-            for name in ("wx", "wz", "wB", "wC", "wdt",
-                         "dt_bias", "A_log", "D", "conv_w", "gate_norm"):
-                out[f"{p}{name}"] = sp[name]
-            out[f"{p}ssm_wo"] = sp["wo"]
-        if "norm2" in lp:
-            out[f"{p}norm2"] = lp["norm2"]
-        if "mlp" in lp:
-            mp = lp["mlp"]
-            if cfg.mlp_type == "swiglu":
-                out[f"{p}wg"] = mp["wg"]
-                out[f"{p}wu"] = mp["wu"]
-            else:
-                out[f"{p}wi"] = mp["wi"]
-            out[f"{p}wo2"] = mp["wo"]
-        if "moe" in lp:
-            mo = lp["moe"]
-            out[f"{p}router"] = mo["router"]
-            out[f"{p}moe_wg"] = mo["wg"]
-            out[f"{p}moe_wu"] = mo["wu"]
-            out[f"{p}moe_wo"] = mo["wo"]
+    trees = {"params": params, "cache": cache}
+    recs = {nm: m.stored for nm, m in graph.inputs.items() if m.stored is not None}
+    recs.update(graph.aux)
+    only_matmul = (_matmul_weights(graph)
+                   if views and not graph.space.mesh_shape else {})
+
+    def leaf(r: Stored):
+        x = trees[r.tree]
+        for k in r.path:
+            x = x[k]
+        return x
+
+    out: Dict[str, Any] = {}
+    blocks: Dict[int, List[str]] = {}
+    for nm, r in recs.items():
+        if r.tree == "params" and not only_matmul.get(nm):
+            blocks.setdefault(-1 if r.block is None else r.block, []).append(nm)
+    for blk in sorted(blocks):
+        entries = {nm: leaf(recs[nm]) if blk < 0 else leaf(recs[nm])[blk]
+                   for nm in sorted(blocks[blk], key=lambda nm: recs[nm].path)}
+        out.update((nm, _plain(recs[nm], entries[nm], graph.inputs.get(nm)))
+                   for nm in blocks[blk])
+    out.update((nm, _in_place(r, leaf(r), graph.inputs[nm]))
+               for nm, r in recs.items() if only_matmul.get(nm))
+    if cache is not None:
+        out.update((nm, leaf(r)[r.block]) for nm, r in recs.items() if r.tree == "cache")
     return out
+
+
+def model_inputs(graph: GraphSpec, cfg, params) -> Dict[str, Any]:
+    """Map the stored model params (``models.transformer`` layout:
+    scanned super-blocks) onto the graph's param inputs and auxiliary
+    tensors as plain arrays, each read where the graph's record says
+    (``axe.graphs.Stored``). Per-head projections take the graph's 2-D
+    views (``wq [d, H, hd] → [d, H·hd]`` — head-major columns, so a
+    solved column sharding is a head sharding of the model leaf)."""
+    return _bind(graph, cfg, params)
 
 
 def model_executable(
@@ -1354,78 +1360,19 @@ def _matmul_weights(graph: GraphSpec) -> Dict[str, bool]:
     }
 
 
-def _weight_views(graph: GraphSpec, cfg, params) -> Dict[str, Any]:
-    """Each matmul weight of the graph as a view of the stored param: a
-    :class:`~repro.kernels.matmul.WeightView` of a 2-D weight (a layer
-    offset into the stacked leaf, whole heads of ``wq``/``wk``/``wv``
-    (``[L, d, H, hd]``), the tied head as ``embed`` transposed), an
-    :class:`~repro.kernels.moe_gemm.ExpertView` of a layer's expert
-    weights (``[L, E, d, f]``). A weight that any other op reads stays a
-    plain array (see ``decode_inputs``)."""
-    from repro.kernels.matmul import WeightView
-    from repro.kernels.moe_gemm import ExpertView
-
-    per = _period(cfg)
-    h, hd, d = cfg.num_heads, cfg.head_dim, cfg.d_model
-    views: Dict[str, Any] = {}
-    if cfg.tie_embeddings:
-        views["lm_head"] = WeightView(params["embed"], transposed=True)
-    for i in _graph_layers(graph):
-        sup, slot = i // per, i % per
-        st = params["blocks"][f"l{slot}"]
-        p = f"L{i}."
-        if "attn" in st:
-            ap = st["attn"]
-            for name in ("wq", "wk", "wv"):
-                views[p + name] = WeightView(ap[name], sup)
-            # [L, H, hd, d] -> [L, H·hd, d] merges whole tiles: a bitcast
-            views[p + "wo"] = WeightView.of_layer(ap["wo"].reshape(-1, h * hd, d), sup)
-        if "ssm" in st:
-            for name in ("wx", "wz", "wB", "wC", "wdt"):
-                views[p + name] = WeightView.of_layer(st["ssm"][name], sup)
-            views[p + "ssm_wo"] = WeightView.of_layer(st["ssm"]["wo"], sup)
-        if "mlp" in st:
-            for name, leaf in (("wg", "wg"), ("wu", "wu"), ("wi", "wi"), ("wo2", "wo")):
-                if leaf in st["mlp"]:
-                    views[p + name] = WeightView.of_layer(st["mlp"][leaf], sup)
-        if "moe" in st:
-            for name in ("wg", "wu", "wo"):
-                views[p + "moe_" + name] = ExpertView(st["moe"][name], sup)
-    weights = _matmul_weights(graph)
-    return {nm: v for nm, v in views.items() if weights.get(nm)}
-
-
 def decode_inputs(graph: GraphSpec, cfg, params, cache) -> Dict[str, Any]:
-    """:func:`model_inputs` plus the cache tensors, for one decode step.
-
-    The cache leaves are sliced per layer out of the reference pytree
-    (``models.transformer`` layout — per-slot dicts stacked over
-    super-blocks) onto the graph's per-layer cache-in names. Each matmul
-    weight is bound as a view of the stored param (:func:`_weight_views`),
-    which the ``matmul/tile`` or ``moe_gemm/expert_gemm`` kernel reads
-    where it lies: no weight is sliced or relaid out per step
-    (:func:`bind_report` counts). Weights read by anything but the kernel
-    (its XLA fallback included) get the plain slice, which XLA fuses into
-    its own op.
-
-    On a mesh (a graph space with mesh axes) every input is a plain
-    array, as :func:`model_inputs` gives it: the executable's
-    ``shard_map`` places each input by its 2-D spec."""
-    out = model_inputs(graph, cfg, params)
-    if not graph.space.mesh_shape:
-        out.update(_weight_views(graph, cfg, params))
-    per = _period(cfg)
-    for i in _graph_layers(graph):
-        sup, slot = i // per, i % per
-        leaf = cache[f"l{slot}"]
-        p = f"L{i}."
-        if "k" in leaf:
-            out[f"{p}k_cache"] = leaf["k"][sup]
-            out[f"{p}v_cache"] = leaf["v"][sup]
-        else:
-            out[f"{p}ssm_state"] = leaf["ssm"][sup]
-            out[f"{p}conv_state"] = leaf["conv"][sup]
-    return out
+    """:func:`model_inputs` plus the cache tensors (each layer's entry of
+    the stacked cache leaf), for one decode step. Each weight only matmuls
+    read is bound as the view its record picks — a
+    :class:`~repro.kernels.matmul.WeightView` or an
+    :class:`~repro.kernels.moe_gemm.ExpertView` — which the
+    ``matmul/tile`` or ``moe_gemm/expert_gemm`` kernel reads where it
+    lies: no weight is sliced or relaid out per step (:func:`bind_report`
+    counts). Weights anything else reads (the kernel's XLA fallback
+    included) get the plain slice, which XLA fuses into its own op. On a
+    mesh every input is a plain array: the executable's ``shard_map``
+    places each input by its 2-D spec."""
+    return _bind(graph, cfg, params, cache, views=True)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1460,23 +1407,22 @@ def bind_report(graph: GraphSpec, params, inputs: Mapping[str, Any]) -> BindRepo
 
 
 def decode_cache(graph: GraphSpec, cfg, outputs: Sequence[Any], cache):
-    """Reassemble the reference cache pytree from a decode executable's
-    output tuple (the cache-out tensors, one pair per layer) — the
-    inverse of :func:`decode_inputs`'s per-layer slicing. ``cache`` is
-    only consulted for leaf kinds (attention vs SSM slots)."""
-    per = _period(cfg)
+    """Reassemble the stored cache pytree from a decode executable's
+    output tuple: each cache-out tensor goes where its cache input's
+    record points, stacked over the super-blocks — the inverse of
+    :func:`decode_inputs`' cache reads. ``cfg`` and ``cache`` are not
+    read."""
     vals = dict(zip(graph.outputs(), outputs))
-    layers = _graph_layers(graph)
-    sups = sorted({i // per for i in layers})
-    new = {}
-    for slot in sorted({i % per for i in layers}):
-        leaf = cache[f"l{slot}"]
-        names = ({"k": "k_cache_out", "v": "v_cache_out"} if "k" in leaf
-                 else {"ssm": "ssm_state_out", "conv": "conv_state_out"})
-        new[f"l{slot}"] = {
-            key: jnp.stack([vals[f"L{s * per + slot}.{g}"] for s in sups])
-            for key, g in names.items()
-        }
+    stacks: Dict[Tuple[str, ...], Dict[int, Any]] = {}
+    for out, src in graph.cache_out.items():
+        r = graph.inputs[src].stored
+        stacks.setdefault(r.path, {})[r.block] = vals[out]
+    new: Dict[str, Any] = {}
+    for path, blocks in stacks.items():
+        node = new
+        for k in path[:-1]:
+            node = node.setdefault(k, {})
+        node[path[-1]] = jnp.stack([blocks[b] for b in sorted(blocks)])
     return new
 
 
@@ -1486,8 +1432,8 @@ def decode_expert_load(graph: GraphSpec, outputs: Sequence[Any]):
     expert) pairs, held experts with a token); None for a graph without
     experts."""
     vals = dict(zip(graph.outputs(), outputs))
-    loads = [vals[n] for n in (f"L{i}.moe_load" for i in _graph_layers(graph))
-             if n in vals]
+    loads = [vals[n.out] for n in graph.nodes
+             if n.kind == "side_output" and n.attr("channel") == "load"]
     return jnp.stack(loads) if loads else None
 
 

@@ -21,7 +21,9 @@ Since ``axe.compile`` these graphs are *executable*: every node carries
 the execution attrs its backend needs (norm weights, rope/qk-norm/mask
 parameters on the q/k/v boundary nodes, router + capacity metadata on
 the MoE nodes, the SSD mixer's auxiliary tensors) referencing small
-replicated auxiliary parameters by name. Projections are split exactly
+replicated auxiliary parameters by name, and records where each param,
+cache and auxiliary tensor is stored (:class:`Stored`), which is all the
+binding in ``axe.compile`` reads. Projections are split exactly
 as the reference models keep them (``wq``/``wk``/``wv``, the SwiGLU
 ``wg``/``wu`` pair, per-expert ``moe_wg``/``moe_wu``) so a solved
 placement of a graph weight is directly a placement of the model leaf
@@ -32,7 +34,7 @@ semantics stay those of the plain op kinds.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 from repro.axe import rules
 from repro.axe.propagate import OpNode
@@ -40,15 +42,40 @@ from repro.axe.spec import AxeSpec, PhysicalSpace
 
 
 @dataclasses.dataclass(frozen=True)
+class Stored:
+    """Where a graph tensor lives in the model's stored pytrees
+    (``models.transformer``): the ``tree`` ("params" or "cache"), the key
+    ``path`` into it, the super-block ``block`` whose entry of the stacked
+    leaf it is (None: the leaf is not stacked), and the ``view`` that
+    gives the graph's shape:
+
+    * ``"slice"`` — the entry as it is;
+    * ``"cols"`` — heads merged into columns (``wq [d, H, hd] → [d, H·hd]``);
+    * ``"rows"`` — heads merged into rows (``wo [H, hd, d] → [H·hd, d]``);
+    * ``"T"`` — transposed (the tied head: ``embed [V, d]`` as ``[d, V]``);
+    * ``"experts"`` — a layer's expert stack ``[E, d, f]``.
+
+    ``axe.compile``'s binder reads these records and nothing else."""
+
+    tree: str
+    path: Tuple[str, ...]
+    block: Optional[int] = None
+    view: str = "slice"
+
+
+@dataclasses.dataclass(frozen=True)
 class TensorMeta:
     """One graph input: logical shape + dtype + the seeded preference
-    list (``rules`` syntax) the baseline plan resolves it with."""
+    list (``rules`` syntax) the baseline plan resolves it with, and where
+    a ``param``/``cache`` input is stored (None where no binder reads it:
+    the encoder–decoder family's weights)."""
 
     name: str
     shape: Tuple[int, ...]
     dtype: str
     role: str                      # "activation" | "param" | "cache"
     prefs: Tuple[Tuple, ...] = ()
+    stored: Optional[Stored] = None
 
 
 @dataclasses.dataclass
@@ -58,12 +85,19 @@ class GraphSpec:
     ``extra_outputs`` names tensors that are graph results *in addition
     to* being consumed by later nodes — the cache-out boundary of the
     decode-step graphs, where the updated KV cache both feeds the
-    attention node and must leave the executable for the next step."""
+    attention node and must leave the executable for the next step.
+
+    ``aux`` records where each auxiliary tensor an execution attr names
+    (norm weights, the router, the SSD mixer's constants) is stored;
+    ``cache_out`` maps each cache-out tensor to the cache input it
+    replaces."""
 
     nodes: List[OpNode]
     inputs: Dict[str, TensorMeta]
     space: PhysicalSpace
     extra_outputs: Tuple[str, ...] = ()
+    aux: Dict[str, Stored] = dataclasses.field(default_factory=dict)
+    cache_out: Dict[str, str] = dataclasses.field(default_factory=dict)
 
     def seeded_env(self) -> Dict[str, AxeSpec]:
         """The rule-engine baseline: first admissible preference per
@@ -96,12 +130,22 @@ class _Builder:
         self.nodes: List[OpNode] = []
         self.inputs: Dict[str, TensorMeta] = {}
         self.extra_outputs: List[str] = []
+        self.aux: Dict[str, Stored] = {}
+        self.cache_out: Dict[str, str] = {}
 
-    def inp(self, name: str, shape, role: str, prefs=(), dtype=None) -> str:
+    def inp(self, name: str, shape, role: str, prefs=(), dtype=None,
+            stored: Optional[Stored] = None) -> str:
         self.inputs[name] = TensorMeta(
             name, tuple(int(s) for s in shape), dtype or self.dtype, role,
-            tuple(tuple(p) for p in prefs),
+            tuple(tuple(p) for p in prefs), stored,
         )
+        return name
+
+    def stored_aux(self, name: str, stored: Optional[Stored]) -> str:
+        """Name an auxiliary tensor (for an execution attr) and record
+        where it is stored."""
+        if stored is not None:
+            self.aux[name] = stored
         return name
 
     def op(self, name: str, kind: str, ins, out: str, attrs=()) -> str:
@@ -122,17 +166,23 @@ class _Builder:
 
     def spec(self) -> GraphSpec:
         return GraphSpec(self.nodes, self.inputs, self.space,
-                         tuple(self.extra_outputs))
+                         tuple(self.extra_outputs), self.aux, self.cache_out)
 
 
-def _layer_window(cfg, i: int):
-    """Per-layer sliding window, mirroring ``models.transformer``:
-    local/global families window the first ``ratio`` layers of each
-    period; otherwise the config window applies uniformly."""
-    if cfg.local_global_ratio:
-        per = cfg.local_global_ratio + 1
-        return cfg.sliding_window if (i % per) < cfg.local_global_ratio else None
-    return cfg.sliding_window
+def _param(layer, *keys: str, view: str = "slice") -> Optional[Stored]:
+    """The stored param ``blocks/l{slot}/<keys>`` of decoder layer
+    ``layer`` (a ``cfg.layer(i)``; None: a block no binder reads)."""
+    if layer is None:
+        return None
+    return Stored("params", ("blocks", f"l{layer.slot}", *keys), layer.block, view)
+
+
+def _cache(b: _Builder, layer, p: str, key: str, shape, prefs, dtype=None) -> str:
+    """Layer ``layer``'s cache input for the stored cache leaf
+    ``l{slot}/<key>``, named by ``rules.CACHE_GRAPH_NAMES``."""
+    return b.inp(f"{p}{rules.CACHE_GRAPH_NAMES[key]}", shape, "cache", prefs,
+                 dtype=dtype,
+                 stored=Stored("cache", (f"l{layer.slot}", key), layer.block))
 
 
 # ---------------------------------------------------------------------------
@@ -142,95 +192,135 @@ def _layer_window(cfg, i: int):
 
 def _attention_block(
     b: _Builder, cfg, batch: int, seq: int, p: str, x_in: str,
-    *, layer_index: int = 0, causal: bool = True,
+    *, layer=None, causal: bool = True,
     kv_from: str = None, kv_tokens: int = None, kv_seq: int = None,
 ) -> str:
     """norm → q/k/v projections → attention → output projection →
     residual. ``kv_from`` switches to cross-attention: K/V project from
-    that tensor (the encoder output) instead of the normed input."""
-    d, h, kv, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    that tensor (the encoder output) instead of the normed input.
+    ``layer`` (``cfg.layer(i)``) windows the attention and locates the
+    stored weights; None for encoder layers and cross-attention."""
+    h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     t = batch * seq
     cross = kv_from is not None
-    x_n = b.op(f"{p}norm_in", "norm", (x_in,), f"{p}x_n",
-               attrs=(("weight", f"{p}norm1"),))
-    # cross-attention weights get non-colliding base names (cwq/cwk/...)
-    # so PlanRules never mistakes them for the self-attention projections
-    wq = b.inp(f"{p}cwq" if cross else f"{p}wq", (d, h * hd), "param",
-               [(None, "model"), (None, None)])
-    wk = b.inp(f"{p}cwk" if cross else f"{p}wk", (d, kv * hd), "param",
-               [(None, "model"), (None, None)])
-    wv = b.inp(f"{p}cwv" if cross else f"{p}wv", (d, kv * hd), "param",
-               [(None, "model"), (None, None)])
-    kv_src = kv_from if cross else x_n
+    x_n = _norm_in(b, layer, p, x_in)
     kv_s = seq if not cross else (
         kv_seq if kv_seq is not None else (kv_tokens // batch)
     )
-    qf = b.op(f"{p}q_proj", "matmul", (x_n, wq), f"{p}qf")
-    kf = b.op(f"{p}k_proj", "matmul", (kv_src, wk), f"{p}kf")
-    vf = b.op(f"{p}v_proj", "matmul", (kv_src, wv), f"{p}vf")
-    # the reference models rope + qk-norm at this boundary (never for
-    # cross-attention), so the select nodes carry those execution attrs
-    rope = None if cross else cfg.rope_theta
-    qk = (not cross) and cfg.qk_norm
-
-    def sel(role, heads, extra=()):
-        # only q and k are rotary-embedded; v passes through
-        theta = rope if role in ("q", "k") else None
-        return (("select", role), ("heads", heads), ("head_dim", hd),
-                ("batch", batch), ("rope_theta", theta)) + tuple(extra)
-
+    qf, kf, vf = _qkv_proj(b, cfg, layer, p, x_n, kv_from if cross else x_n,
+                           "c" if cross else "")
     q = b.reshape(f"{p}q", qf, (batch, h, seq, hd), ((0, 0), (1, 1)),
-                  extra=sel("q", h, (("norm_weight", f"{p}q_norm" if qk else None),)))
+                  extra=_select(b, cfg, layer, p, "q", batch, cross))
     k = b.reshape(f"{p}k", kf, (batch, kv, kv_s, hd), ((0, 0), (1, 1)),
-                  extra=sel("k", kv, (("norm_weight", f"{p}k_norm" if qk else None),)))
+                  extra=_select(b, cfg, layer, p, "k", batch, cross))
     v = b.reshape(f"{p}v", vf, (batch, kv, kv_s, hd), ((0, 0), (1, 1)),
-                  extra=sel("v", kv))
+                  extra=_select(b, cfg, layer, p, "v", batch, cross))
     attn = b.op(f"{p}attention", "attention", (q, k, v), f"{p}attn_out",
                 attrs=(("causal", causal and not cross),
-                       ("window", None if cross else _layer_window(cfg, layer_index))))
+                       ("window", None if cross or layer is None else layer.window)))
     flat = b.reshape(f"{p}attn_flat", attn, (t, h * hd), ((0, 0), (1, 1)),
                      extra=(("select", "merge_heads"), ("batch", batch)))
-    wo = b.inp(f"{p}cwo" if cross else f"{p}wo",
-               (h * hd, d), "param", [("model", None), (None, None)])
+    return _attn_out(b, cfg, layer, p, flat, x_in, "c" if cross else "")
+
+
+def _norm_in(b: _Builder, layer, p: str, x_in: str) -> str:
+    return b.op(f"{p}norm_in", "norm", (x_in,), f"{p}x_n",
+                attrs=(("weight", b.stored_aux(f"{p}norm1", _param(layer, "norm1"))),))
+
+
+def _qkv_proj(b: _Builder, cfg, layer, p: str, x_n: str, kv_src: str, c: str = ""):
+    """The q/k/v weights and projections. Cross-attention weights
+    (``c="c"``) get non-colliding base names (cwq/cwk/...) so PlanRules
+    never mistakes them for the self-attention projections."""
+    d, hd = cfg.d_model, cfg.head_dim
+    out = []
+    for w, heads, src in (("q", cfg.num_heads, x_n), ("k", cfg.num_kv_heads, kv_src),
+                          ("v", cfg.num_kv_heads, kv_src)):
+        wt = b.inp(f"{p}{c}w{w}", (d, heads * hd), "param",
+                   [(None, "model"), (None, None)],
+                   stored=_param(layer, "attn", f"w{w}", view="cols"))
+        out.append((src, wt, w))
+    return tuple(b.op(f"{p}{w}_proj", "matmul", (src, wt), f"{p}{w}f")
+                 for src, wt, w in out)
+
+
+def _attn_out(b: _Builder, cfg, layer, p: str, flat: str, x_in: str, c: str = "") -> str:
+    """Output projection → residual."""
+    hd, h = cfg.head_dim, cfg.num_heads
+    wo = b.inp(f"{p}{c}wo", (h * hd, cfg.d_model), "param",
+               [("model", None), (None, None)],
+               stored=_param(layer, "attn", "wo", view="rows"))
     o = b.op(f"{p}wo_proj", "matmul", (flat, wo), f"{p}attn_o")
     return b.op(f"{p}attn_residual", "elementwise", (o, x_in), f"{p}x1",
                 attrs=(("fn", "add"),))
 
 
-def _ssm_block(b: _Builder, cfg, batch: int, seq: int, p: str, x_in: str) -> str:
-    """norm → (x/z/B/C/dt projections) → SSD mix → gate → gated norm →
-    out proj → residual; the Mamba2 mixer as layout ops."""
+def _select(b: _Builder, cfg, layer, p: str, role: str, batch: int,
+            cross: bool = False):
+    """The execution attrs of the q/k/v boundary: the reference models
+    split heads there, rotary-embed q and k, and qk-norm them (never for
+    cross-attention)."""
+    heads = cfg.num_heads if role == "q" else cfg.num_kv_heads
+    rope = role != "v" and not cross
+    attrs = (("select", role), ("heads", heads), ("head_dim", cfg.head_dim),
+             ("batch", batch), ("rope_theta", cfg.rope_theta if rope else None))
+    if role == "v":
+        return attrs
+    norm = (b.stored_aux(f"{p}{role}_norm", _param(layer, "attn", f"{role}_norm"))
+            if cfg.qk_norm and not cross else None)
+    return attrs + (("norm_weight", norm),)
+
+
+def _ssm_in(b: _Builder, cfg, layer, p: str, x_in: str):
+    """The SSD mixer's input half: norm → x/z/B/C/dt projections. Returns
+    the projections and the mixer's execution attrs (its constants)."""
     d = cfg.d_model
     di, n, h = cfg.ssm_d_inner, cfg.ssm_state, cfg.ssm_heads
-    x_n = b.op(f"{p}norm_in", "norm", (x_in,), f"{p}x_n",
-               attrs=(("weight", f"{p}norm1"),))
-    wx = b.inp(f"{p}wx", (d, di), "param", [(None, "model"), (None, None)])
-    wz = b.inp(f"{p}wz", (d, di), "param", [(None, "model"), (None, None)])
-    wB = b.inp(f"{p}wB", (d, n), "param", [(None, None)])
-    wC = b.inp(f"{p}wC", (d, n), "param", [(None, None)])
-    wdt = b.inp(f"{p}wdt", (d, h), "param", [(None, "model"), (None, None)])
-    xz = b.op(f"{p}x_proj", "matmul", (x_n, wx), f"{p}xz")
-    zz = b.op(f"{p}z_proj", "matmul", (x_n, wz), f"{p}zz")
-    bb = b.op(f"{p}b_proj", "matmul", (x_n, wB), f"{p}bb")
-    cc = b.op(f"{p}c_proj", "matmul", (x_n, wC), f"{p}cc")
-    dt = b.op(f"{p}dt_proj", "matmul", (x_n, wdt), f"{p}dt")
-    y = b.op(f"{p}ssm_mix", "ssm_mix", (xz, bb, cc, dt), f"{p}y",
-             attrs=(("batch", batch), ("seq", seq),
-                    ("heads", h), ("head_dim", cfg.ssm_headdim),
-                    ("state", n), ("d_inner", di),
-                    ("dt_bias", f"{p}dt_bias"), ("A_log", f"{p}A_log"),
-                    ("D", f"{p}D"), ("conv_w", f"{p}conv_w")))
+    x_n = _norm_in(b, layer, p, x_in)
+    outs = []
+    for w, shape, prefs, proj, out in (
+            ("wx", (d, di), [(None, "model"), (None, None)], "x_proj", "xz"),
+            ("wz", (d, di), [(None, "model"), (None, None)], "z_proj", "zz"),
+            ("wB", (d, n), [(None, None)], "b_proj", "bb"),
+            ("wC", (d, n), [(None, None)], "c_proj", "cc"),
+            ("wdt", (d, h), [(None, "model"), (None, None)], "dt_proj", "dt")):
+        outs.append((b.inp(f"{p}{w}", shape, "param", prefs,
+                           stored=_param(layer, "ssm", w)), proj, out))
+    projs = tuple(b.op(f"{p}{proj}", "matmul", (x_n, w), f"{p}{out}")
+                  for w, proj, out in outs)
+    consts = tuple((c, b.stored_aux(f"{p}{c}", _param(layer, "ssm", c)))
+                   for c in ("dt_bias", "A_log", "D", "conv_w"))
+    return projs, (("heads", h), ("head_dim", cfg.ssm_headdim),
+                   ("state", n), ("d_inner", di)) + consts
+
+
+def _ssm_out(b: _Builder, cfg, layer, p: str, y: str, zz: str, x_in: str) -> str:
+    """The SSD mixer's output half: gate → gated norm → out proj →
+    residual."""
     g = b.op(f"{p}gate", "elementwise", (y, zz), f"{p}g",
              attrs=(("fn", "mul_silu"),))
     gn = b.op(f"{p}gate_norm", "norm", (g,), f"{p}gn",
-              attrs=(("weight", f"{p}gate_norm"),))
-    wo = b.inp(f"{p}ssm_wo", (di, d), "param", [("model", None), (None, None)])
+              attrs=(("weight", b.stored_aux(f"{p}gate_norm",
+                                             _param(layer, "ssm", "gate_norm"))),))
+    wo = b.inp(f"{p}ssm_wo", (cfg.ssm_d_inner, cfg.d_model), "param",
+               [("model", None), (None, None)], stored=_param(layer, "ssm", "wo"))
     o = b.op(f"{p}out_proj", "matmul", (gn, wo), f"{p}ssm_o")
     return b.op(f"{p}ssm_residual", "elementwise", (o, x_in), f"{p}x1",
                 attrs=(("fn", "add"),))
 
 
-def _ffn_block(b: _Builder, cfg, t: int, p: str, x_in: str, res: str) -> str:
+def _ssm_block(b: _Builder, cfg, batch: int, seq: int, p: str, x_in: str,
+               layer=None) -> str:
+    """norm → (x/z/B/C/dt projections) → SSD mix → gate → gated norm →
+    out proj → residual; the Mamba2 mixer as layout ops."""
+    (xz, zz, bb, cc, dt), attrs = _ssm_in(b, cfg, layer, p, x_in)
+    y = b.op(f"{p}ssm_mix", "ssm_mix", (xz, bb, cc, dt), f"{p}y",
+             attrs=(("batch", batch), ("seq", seq)) + attrs)
+    return _ssm_out(b, cfg, layer, p, y, zz, x_in)
+
+
+def _ffn_block(b: _Builder, cfg, t: int, p: str, x_in: str, res: str,
+               layer=None) -> str:
     """norm → dense FFN or MoE dispatch/expert-GEMMs/combine → residual.
 
     The FFN keeps the reference models' structure — a SwiGLU gate pair
@@ -239,25 +329,29 @@ def _ffn_block(b: _Builder, cfg, t: int, p: str, x_in: str, res: str) -> str:
     reproduces the exact activation math."""
     d = cfg.d_model
     x2 = b.op(f"{p}norm_ffn", "norm", (x_in,), f"{p}x2",
-              attrs=(("weight", f"{p}norm2"),))
+              attrs=(("weight", b.stored_aux(f"{p}norm2", _param(layer, "norm2"))),))
     if cfg.is_moe:
         # the experts held here (all, or this device's share); the router
         # keeps every expert. One buffer row per token: no drops
         e, f_e = cfg.moe_experts_held, cfg.moe_d_ff
         moe_wg = b.inp(f"{p}moe_wg", (e, d, f_e), "param",
                        [("model", None, None), (None, None, "model"),
-                        (None, None, None)])
+                        (None, None, None)],
+                       stored=_param(layer, "moe", "wg", view="experts"))
         moe_wu = b.inp(f"{p}moe_wu", (e, d, f_e), "param",
                        [("model", None, None), (None, None, "model"),
-                        (None, None, None)])
+                        (None, None, None)],
+                       stored=_param(layer, "moe", "wu", view="experts"))
         moe_wo = b.inp(f"{p}moe_wo", (e, f_e, d), "param",
                        [("model", None, None), (None, "model", None),
-                        (None, None, None)])
+                        (None, None, None)],
+                       stored=_param(layer, "moe", "wo", view="experts"))
+        router = b.stored_aux(f"{p}router", _param(layer, "moe", "router"))
         xe = b.op(f"{p}moe_dispatch", "moe_dispatch", (x2,), f"{p}xe",
                   attrs=(("experts", e), ("capacity", t),
                          ("experts_per_tok", cfg.experts_per_tok),
                          ("expert_offset", cfg.expert_offset),
-                         ("router", f"{p}router")))
+                         ("router", router)))
         hg = b.op(f"{p}moe_ffn_g", "matmul", (xe, moe_wg), f"{p}hg")
         hu = b.op(f"{p}moe_ffn_u", "matmul", (xe, moe_wu), f"{p}hu")
         ha = b.op(f"{p}moe_act", "elementwise", (hg, hu), f"{p}ha",
@@ -270,30 +364,25 @@ def _ffn_block(b: _Builder, cfg, t: int, p: str, x_in: str, res: str) -> str:
         return b.op(f"{p}ffn_residual", "elementwise", (out, res), f"{p}x_out",
                     attrs=(("fn", "add"),))
     if cfg.mlp_type == "swiglu":
-        wg = b.inp(f"{p}wg", (d, cfg.d_ff), "param", [(None, "model"), (None, None)])
-        wu = b.inp(f"{p}wu", (d, cfg.d_ff), "param", [(None, "model"), (None, None)])
+        wg = b.inp(f"{p}wg", (d, cfg.d_ff), "param", [(None, "model"), (None, None)],
+                   stored=_param(layer, "mlp", "wg"))
+        wu = b.inp(f"{p}wu", (d, cfg.d_ff), "param", [(None, "model"), (None, None)],
+                   stored=_param(layer, "mlp", "wu"))
         hg = b.op(f"{p}ffn_g", "matmul", (x2, wg), f"{p}hgd")
         hu = b.op(f"{p}ffn_u", "matmul", (x2, wu), f"{p}hud")
         hh = b.op(f"{p}ffn_act", "elementwise", (hg, hu), f"{p}ffn_h",
                   attrs=(("fn", "swiglu"),))
     else:
-        wi = b.inp(f"{p}wi", (d, cfg.d_ff), "param", [(None, "model"), (None, None)])
+        wi = b.inp(f"{p}wi", (d, cfg.d_ff), "param", [(None, "model"), (None, None)],
+                   stored=_param(layer, "mlp", "wi"))
         h0 = b.op(f"{p}ffn_in", "matmul", (x2, wi), f"{p}ffn_h0")
         hh = b.op(f"{p}ffn_act", "elementwise", (h0,), f"{p}ffn_h",
                   attrs=(("fn", "gelu"),))
-    wo2 = b.inp(f"{p}wo2", (cfg.d_ff, d), "param", [("model", None), (None, None)])
+    wo2 = b.inp(f"{p}wo2", (cfg.d_ff, d), "param", [("model", None), (None, None)],
+                stored=_param(layer, "mlp", "wo"))
     oo = b.op(f"{p}ffn_out", "matmul", (hh, wo2), f"{p}ffn_o")
     return b.op(f"{p}ffn_residual", "elementwise", (oo, res), f"{p}x_out",
                 attrs=(("fn", "add"),))
-
-
-def _mixer_kind(cfg, i: int) -> str:
-    if cfg.family == "ssm":
-        return "ssm"
-    if cfg.family == "hybrid":
-        per = max(cfg.attn_period, 1)
-        return "attn" if i % per == per - 1 else "ssm"
-    return "attn"
 
 
 def _decoder_layer(
@@ -303,20 +392,42 @@ def _decoder_layer(
 ) -> str:
     """One decoder layer; returns the layer output tensor name."""
     t = batch * seq
-    if _mixer_kind(cfg, layer_index) == "ssm":
-        x1 = _ssm_block(b, cfg, batch, seq, p, x_in)
+    layer = cfg.layer(layer_index)
+    if enc_out is not None:
+        # encoder-decoder: cross-attention sub-block after self-attn.
+        # models.encdec stores its layers another way: no records
+        layer = None
+        x1 = _attention_block(b, cfg, batch, seq, p, x_in)
+        x1 = _attention_block(
+            b, cfg, batch, seq, f"{p}cross.", x1,
+            kv_from=enc_out, kv_tokens=enc_tokens, kv_seq=enc_seq,
+        )
+    elif layer.mixer == "ssm":
+        x1 = _ssm_block(b, cfg, batch, seq, p, x_in, layer)
     else:
-        x1 = _attention_block(b, cfg, batch, seq, p, x_in,
-                              layer_index=layer_index)
-        if enc_out is not None:
-            # encoder-decoder: cross-attention sub-block after self-attn
-            x1 = _attention_block(
-                b, cfg, batch, seq, f"{p}cross.", x1,
-                kv_from=enc_out, kv_tokens=enc_tokens, kv_seq=enc_seq,
-            )
+        x1 = _attention_block(b, cfg, batch, seq, p, x_in, layer=layer)
     if not (cfg.is_moe or cfg.d_ff):
         return x1  # pure SSM block (mamba2): mixer only
-    return _ffn_block(b, cfg, t, p, x1, x1)
+    return _ffn_block(b, cfg, t, p, x1, x1, layer)
+
+
+def _embed(b: _Builder, cfg, tokens: str) -> str:
+    embed = b.inp("embed", (cfg.vocab_size, cfg.d_model), "param",
+                  list(rules.PARAM_RULES["embed"]),
+                  stored=Stored("params", ("embed",)))
+    return b.op("embed_lookup", "embed", (tokens, embed), "x0")
+
+
+def _head(b: _Builder, cfg, x: str) -> str:
+    """final norm → lm_head; a tied head is ``embed`` transposed."""
+    x_f = b.op("final_norm", "norm", (x,), "x_f",
+               attrs=(("weight", b.stored_aux("final_norm",
+                                              Stored("params", ("final_norm",)))),))
+    head = (Stored("params", ("embed",), view="T") if cfg.tie_embeddings
+            else Stored("params", ("lm_head",)))
+    lm_head = b.inp("lm_head", (cfg.d_model, cfg.vocab_size), "param",
+                    list(rules.PARAM_RULES["lm_head"]), stored=head)
+    return b.op("lm_head_proj", "matmul", (x_f, lm_head), "logits")
 
 
 # ---------------------------------------------------------------------------
@@ -371,19 +482,17 @@ def model_graph(
     every cross-layer boundary)."""
     b = _Builder(space, dtype)
     dp = rules.dp_entry(space)
-    d, v = cfg.d_model, cfg.vocab_size
     t = batch * seq
 
     tokens = b.inp("tokens", (t,), "activation", [(dp,), (None,)], dtype="int32")
-    embed = b.inp("embed", (v, d), "param", list(rules.PARAM_RULES["embed"]))
-    x = b.op("embed_lookup", "embed", (tokens, embed), "x0")
+    x = _embed(b, cfg, tokens)
 
     enc_out = None
     enc_t = enc_s = None
     if cfg.family == "encdec":
         enc_s = cfg.encoder_seq
         enc_t = batch * enc_s
-        frames = b.inp("frames", (enc_t, d), "activation",
+        frames = b.inp("frames", (enc_t, cfg.d_model), "activation",
                        [(dp, None), (None, None)])
         e_x = frames
         for i in range(min(cfg.encoder_layers, layers)):
@@ -400,10 +509,7 @@ def model_graph(
             layer_index=i, enc_out=enc_out, enc_tokens=enc_t, enc_seq=enc_s,
         )
 
-    x_f = b.op("final_norm", "norm", (x,), "x_f",
-               attrs=(("weight", "final_norm"),))
-    lm_head = b.inp("lm_head", (d, v), "param", list(rules.PARAM_RULES["lm_head"]))
-    b.op("lm_head_proj", "matmul", (x_f, lm_head), "logits")
+    _head(b, cfg, x)
     return b.spec()
 
 
@@ -421,44 +527,26 @@ def cache_window(cfg, layer_index: int, max_seq: int) -> int:
     """The cache length of one layer: its sliding window (ring buffer)
     capped at ``max_seq``, or the full ``max_seq`` — exactly
     ``models.transformer.cache_init``'s per-layer allocation."""
-    w = _layer_window(cfg, layer_index)
+    w = cfg.layer(layer_index).window
     return min(w, max_seq) if w else max_seq
 
 
 def _attention_decode_block(
-    b: _Builder, cfg, batch: int, max_seq: int, p: str, x_in: str,
-    *, layer_index: int = 0,
+    b: _Builder, cfg, batch: int, max_seq: int, p: str, x_in: str, layer,
 ) -> str:
     """One decode step of the attention mixer: norm → q/k/v projections
     → rope/qk-norm at the *runtime* position (``decode_select``) → cache
     write at that position (``cache_update`` — the cache-in/cache-out
     boundary) → single-token attention over the laid-out cache
     (``decode_attention``) → output projection → residual."""
-    d, h, kv, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    window = _layer_window(cfg, layer_index)
-    w_len = cache_window(cfg, layer_index, max_seq)
-    ring = window is not None
-    x_n = b.op(f"{p}norm_in", "norm", (x_in,), f"{p}x_n",
-               attrs=(("weight", f"{p}norm1"),))
-    wq = b.inp(f"{p}wq", (d, h * hd), "param", [(None, "model"), (None, None)])
-    wk = b.inp(f"{p}wk", (d, kv * hd), "param", [(None, "model"), (None, None)])
-    wv = b.inp(f"{p}wv", (d, kv * hd), "param", [(None, "model"), (None, None)])
-    qf = b.op(f"{p}q_proj", "matmul", (x_n, wq), f"{p}qf")
-    kf = b.op(f"{p}k_proj", "matmul", (x_n, wk), f"{p}kf")
-    vf = b.op(f"{p}v_proj", "matmul", (x_n, wv), f"{p}vf")
-    qk = cfg.qk_norm
-
-    def sel(role, heads, extra=()):
-        theta = cfg.rope_theta if role in ("q", "k") else None
-        return (("select", role), ("heads", heads), ("head_dim", hd),
-                ("batch", batch), ("rope_theta", theta)) + tuple(extra)
-
-    q = b.op(f"{p}q", "decode_select", (qf, "pos"), f"{p}q",
-             attrs=sel("q", h, (("norm_weight", f"{p}q_norm" if qk else None),)))
-    k = b.op(f"{p}k", "decode_select", (kf, "pos"), f"{p}k",
-             attrs=sel("k", kv, (("norm_weight", f"{p}k_norm" if qk else None),)))
-    v = b.op(f"{p}v", "decode_select", (vf, "pos"), f"{p}v",
-             attrs=sel("v", kv))
+    h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    w_len = min(layer.window, max_seq) if layer.window else max_seq
+    ring = layer.window is not None
+    x_n = _norm_in(b, layer, p, x_in)
+    qf, kf, vf = _qkv_proj(b, cfg, layer, p, x_n, x_n)
+    q, k, v = (b.op(f"{p}{r}", "decode_select", (src, "pos"), f"{p}{r}",
+                    attrs=_select(b, cfg, layer, p, r, batch))
+               for r, src in (("q", qf), ("k", kf), ("v", vf)))
     # cache-in: a first-class graph tensor the solver places like any
     # other (batch-sharded and/or kv-head-sharded; the ring/linear write
     # keeps the position dim locally complete)
@@ -466,12 +554,13 @@ def _attention_decode_block(
                    (None, None, "model", None),
                    (rules.dp_entry(b.space), None, None, None),
                    (None, None, None, None)]
-    k_cache = b.inp(f"{p}k_cache", (batch, w_len, kv, hd), "cache", cache_prefs)
-    v_cache = b.inp(f"{p}v_cache", (batch, w_len, kv, hd), "cache", cache_prefs)
+    k_cache = _cache(b, layer, p, "k", (batch, w_len, kv, hd), cache_prefs)
+    v_cache = _cache(b, layer, p, "v", (batch, w_len, kv, hd), cache_prefs)
     kco = b.op(f"{p}k_cache_write", "cache_update", (k_cache, k, "pos"),
                f"{p}k_cache_out", attrs=(("ring", ring),))
     vco = b.op(f"{p}v_cache_write", "cache_update", (v_cache, v, "pos"),
                f"{p}v_cache_out", attrs=(("ring", ring),))
+    b.cache_out.update({kco: k_cache, vco: v_cache})
     b.mark_output(kco)
     b.mark_output(vco)
     attn = b.op(f"{p}decode_attention", "decode_attention",
@@ -479,58 +568,32 @@ def _attention_decode_block(
                 attrs=(("ring", ring),))
     flat = b.reshape(f"{p}attn_flat", attn, (batch, h * hd), ((0, 0), (1, 1)),
                      extra=(("select", "merge_heads"), ("batch", batch)))
-    wo = b.inp(f"{p}wo", (h * hd, d), "param", [("model", None), (None, None)])
-    o = b.op(f"{p}wo_proj", "matmul", (flat, wo), f"{p}attn_o")
-    return b.op(f"{p}attn_residual", "elementwise", (o, x_in), f"{p}x1",
-                attrs=(("fn", "add"),))
+    return _attn_out(b, cfg, layer, p, flat, x_in)
 
 
-def _ssm_decode_block(b: _Builder, cfg, batch: int, p: str, x_in: str) -> str:
+def _ssm_decode_block(b: _Builder, cfg, batch: int, p: str, x_in: str, layer) -> str:
     """One decode step of the SSD mixer: the recurrent state and the
     causal-conv history are cache-in tensors; ``ssm_decode`` advances
     them one token and the ``side_output`` boundary nodes surface the
     new states as graph outputs."""
-    d = cfg.d_model
     di, n, h = cfg.ssm_d_inner, cfg.ssm_state, cfg.ssm_heads
     dp = rules.dp_entry(b.space)
-    x_n = b.op(f"{p}norm_in", "norm", (x_in,), f"{p}x_n",
-               attrs=(("weight", f"{p}norm1"),))
-    wx = b.inp(f"{p}wx", (d, di), "param", [(None, "model"), (None, None)])
-    wz = b.inp(f"{p}wz", (d, di), "param", [(None, "model"), (None, None)])
-    wB = b.inp(f"{p}wB", (d, n), "param", [(None, None)])
-    wC = b.inp(f"{p}wC", (d, n), "param", [(None, None)])
-    wdt = b.inp(f"{p}wdt", (d, h), "param", [(None, "model"), (None, None)])
-    xz = b.op(f"{p}x_proj", "matmul", (x_n, wx), f"{p}xz")
-    zz = b.op(f"{p}z_proj", "matmul", (x_n, wz), f"{p}zz")
-    bb = b.op(f"{p}b_proj", "matmul", (x_n, wB), f"{p}bb")
-    cc = b.op(f"{p}c_proj", "matmul", (x_n, wC), f"{p}cc")
-    dt = b.op(f"{p}dt_proj", "matmul", (x_n, wdt), f"{p}dt")
-    ssm_state = b.inp(f"{p}ssm_state", (batch, h, n, cfg.ssm_headdim), "cache",
-                      [(dp, None, None, None), (None, None, None, None)],
-                      dtype="float32")
-    conv_state = b.inp(f"{p}conv_state", (batch, CONV_K - 1, di + 2 * n), "cache",
-                       [(dp, None, None), (None, None, None)])
+    (xz, zz, bb, cc, dt), attrs = _ssm_in(b, cfg, layer, p, x_in)
+    ssm_state = _cache(b, layer, p, "ssm", (batch, h, n, cfg.ssm_headdim),
+                       [(dp, None, None, None), (None, None, None, None)],
+                       dtype="float32")
+    conv_state = _cache(b, layer, p, "conv", (batch, CONV_K - 1, di + 2 * n),
+                        [(dp, None, None), (None, None, None)])
     y = b.op(f"{p}ssm_decode", "ssm_decode",
              (xz, bb, cc, dt, ssm_state, conv_state), f"{p}y",
-             attrs=(("batch", batch),
-                    ("heads", h), ("head_dim", cfg.ssm_headdim),
-                    ("state", n), ("d_inner", di),
-                    ("dt_bias", f"{p}dt_bias"), ("A_log", f"{p}A_log"),
-                    ("D", f"{p}D"), ("conv_w", f"{p}conv_w")))
+             attrs=(("batch", batch),) + attrs)
     # cache-out boundary: the advanced states the mixer computed, typed
     # like their cache-in tensors
-    b.op(f"{p}ssm_state_write", "side_output", (y,), f"{p}ssm_state_out",
-         attrs=(("side", y), ("channel", "ssm"), ("like", ssm_state)))
-    b.op(f"{p}conv_state_write", "side_output", (y,), f"{p}conv_state_out",
-         attrs=(("side", y), ("channel", "conv"), ("like", conv_state)))
-    g = b.op(f"{p}gate", "elementwise", (y, zz), f"{p}g",
-             attrs=(("fn", "mul_silu"),))
-    gn = b.op(f"{p}gate_norm", "norm", (g,), f"{p}gn",
-              attrs=(("weight", f"{p}gate_norm"),))
-    wo = b.inp(f"{p}ssm_wo", (di, d), "param", [("model", None), (None, None)])
-    o = b.op(f"{p}out_proj", "matmul", (gn, wo), f"{p}ssm_o")
-    return b.op(f"{p}ssm_residual", "elementwise", (o, x_in), f"{p}x1",
-                attrs=(("fn", "add"),))
+    for cin, channel in ((ssm_state, "ssm"), (conv_state, "conv")):
+        out = b.op(f"{cin}_write", "side_output", (y,), f"{cin}_out",
+                   attrs=(("side", y), ("channel", channel), ("like", cin)))
+        b.cache_out[out] = cin
+    return _ssm_out(b, cfg, layer, p, y, zz, x_in)
 
 
 def decode_graph(
@@ -555,23 +618,20 @@ def decode_graph(
     caches come back as graph outputs alongside ``logits``."""
     b = _Builder(space, dtype)
     dp = rules.dp_entry(space)
-    d, v = cfg.d_model, cfg.vocab_size
 
-    b.inp("tokens", (batch,), "activation", [(dp,), (None,)], dtype="int32")
+    tokens = b.inp("tokens", (batch,), "activation", [(dp,), (None,)], dtype="int32")
     b.inp("pos", (batch,), "activation", [(dp,), (None,)], dtype="int32")
-    embed = b.inp("embed", (v, d), "param", list(rules.PARAM_RULES["embed"]))
-    x = b.op("embed_lookup", "embed", ("tokens", embed), "x0")
+    x = _embed(b, cfg, tokens)
 
     n_layers = cfg.num_layers if layers is None else min(cfg.num_layers, layers)
     for i in range(n_layers):
-        p = f"L{i}."
-        if _mixer_kind(cfg, i) == "ssm":
-            x = _ssm_decode_block(b, cfg, batch, p, x)
+        p, layer = f"L{i}.", cfg.layer(i)
+        if layer.mixer == "ssm":
+            x = _ssm_decode_block(b, cfg, batch, p, x, layer)
         else:
-            x = _attention_decode_block(b, cfg, batch, max_seq, p, x,
-                                        layer_index=i)
+            x = _attention_decode_block(b, cfg, batch, max_seq, p, x, layer)
         if cfg.is_moe or cfg.d_ff:
-            x = _ffn_block(b, cfg, batch, p, x, x)
+            x = _ffn_block(b, cfg, batch, p, x, x, layer)
         if cfg.is_moe:
             # the expert layer's load (``moe.load``): a graph output read
             # after serving, never inside a step
@@ -579,8 +639,5 @@ def decode_graph(
                  attrs=(("side", f"{p}xe"), ("channel", "load"),
                         ("shape", (2,)), ("dtype", "int32")))
 
-    x_f = b.op("final_norm", "norm", (x,), "x_f",
-               attrs=(("weight", "final_norm"),))
-    lm_head = b.inp("lm_head", (d, v), "param", list(rules.PARAM_RULES["lm_head"]))
-    b.op("lm_head_proj", "matmul", (x_f, lm_head), "logits")
+    _head(b, cfg, x)
     return b.spec()

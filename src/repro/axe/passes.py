@@ -320,8 +320,7 @@ class EpilogueFusion(Pass):
             out_nodes.append(fused)
 
         return (
-            GraphSpec(out_nodes, dict(graph.inputs), graph.space,
-                      graph.extra_outputs),
+            dataclasses.replace(graph, nodes=out_nodes, inputs=dict(graph.inputs)),
             report,
         )
 
@@ -384,8 +383,7 @@ class ReshapePairCollapse(Pass):
                 changed = True
                 break
         return (
-            GraphSpec(nodes, dict(graph.inputs), graph.space,
-                      graph.extra_outputs),
+            dataclasses.replace(graph, nodes=nodes, inputs=dict(graph.inputs)),
             report,
         )
 
@@ -441,7 +439,7 @@ class DeadCodeElimination(Pass):
             if name in needed or meta.role == "activation"
         }
         return (
-            GraphSpec(nodes, inputs, graph.space, graph.extra_outputs),
+            dataclasses.replace(graph, nodes=nodes, inputs=inputs),
             report,
         )
 

@@ -339,8 +339,9 @@ def batch_specs(batch: Mapping[str, Any], space: PhysicalSpace) -> Dict[str, Axe
     return out
 
 
-#: cache-leaf basename -> decode-graph cache tensor basename
-#: (``repro.axe.graphs.decode_graph`` input names, layer prefix stripped)
+#: cache-leaf basename -> decode-graph cache tensor basename: the names
+#: ``repro.axe.graphs.decode_graph`` gives its cache inputs (layer prefix
+#: stripped), and the leaves their records point at
 CACHE_GRAPH_NAMES = {
     "k": "k_cache", "v": "v_cache", "ck": "k_cache", "cv": "v_cache",
     "ssm": "ssm_state", "conv": "conv_state",

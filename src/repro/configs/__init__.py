@@ -41,15 +41,9 @@ def list_configs() -> List[str]:
 def smoke_variant(cfg: ModelConfig) -> ModelConfig:
     """Reduced same-family config for CPU smoke tests: small widths,
     few layers (one super-block period), tiny vocab/experts."""
-    per = 1
-    if cfg.local_global_ratio:
-        per = cfg.local_global_ratio + 1
-    elif cfg.attn_period:
-        per = cfg.attn_period
-    layers = per if per > 1 else 2
     kw = dict(
         name=cfg.name + "-smoke",
-        num_layers=layers,
+        num_layers=max(cfg.period, 2),
         d_model=256,
         num_heads=4,
         num_kv_heads=2,

@@ -2,7 +2,19 @@
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import NamedTuple, Optional
+
+
+class Layer(NamedTuple):
+    """Where decoder layer ``i`` sits and what it is: the params and
+    caches stack each ``slot`` of a super-block (pytree key ``l{slot}``)
+    over the super-blocks, and the layer's leaves are entry ``block`` of
+    those stacks."""
+
+    mixer: str                 # "attn" | "ssm"
+    window: Optional[int]      # sliding window; None attends to the whole cache
+    block: int                 # super-block index
+    slot: int                  # position inside the super-block
 
 
 @dataclasses.dataclass(frozen=True)
@@ -60,6 +72,37 @@ class ModelConfig:
                     f"{self.name}: experts_held={self.experts_held}, expert_rank="
                     f"{self.expert_rank} is no share of {self.num_experts} experts")
 
+    # ---- layer structure: the one rule the model, its graphs and its binding read ----
+    @property
+    def period(self) -> int:
+        """Layers per super-block: 5 local + 1 global (local/global
+        families), 1 attention per ``attn_period`` (hybrid), else 1."""
+        if self.local_global_ratio:
+            return self.local_global_ratio + 1
+        return self.attn_period or 1
+
+    @property
+    def superblocks(self) -> int:
+        """How many super-blocks the stacked params and caches hold."""
+        assert self.num_layers % self.period == 0, (self.name, self.num_layers, self.period)
+        return self.num_layers // self.period
+
+    def layer(self, i: int) -> Layer:
+        """Decoder layer ``i``: a hybrid's last layer of each period is
+        attention, the rest SSM; a local/global family windows the first
+        ``local_global_ratio`` layers of each period."""
+        block, slot = divmod(i, self.period)
+        if self.family == "ssm":
+            mixer = "ssm"
+        elif self.attn_period:
+            mixer = "attn" if slot == self.period - 1 else "ssm"
+        else:
+            mixer = "attn"
+        window = self.sliding_window
+        if self.local_global_ratio and slot >= self.local_global_ratio:
+            window = None
+        return Layer(mixer, window, block, slot)
+
     # ---- derived ----
     @property
     def is_moe(self) -> bool:
@@ -110,7 +153,7 @@ class ModelConfig:
         elif self.family == "hybrid":
             di, n, hs = self.ssm_d_inner, self.ssm_state, self.ssm_heads
             ssm_block = 2 * d * di + 2 * d * n + d * hs + di * d + 3 * hs
-            n_attn = self.num_layers // max(self.attn_period, 1)
+            n_attn = sum(self.layer(i).mixer == "attn" for i in range(self.num_layers))
             n_ssm = self.num_layers - n_attn
             total = n_attn * (attn + mlp + norms) + n_ssm * (ssm_block + mlp + norms)
         elif self.family == "encdec":

@@ -10,13 +10,15 @@ dry-run). Families with periodic structure scan over *super-blocks*:
 * mamba2  (ssm):                  block = norm + SSD (no FFN)
 * dense / moe / vlm:              uniform layers
 
+(``ModelConfig.period`` / ``ModelConfig.layer`` hold the rule.)
+
 This preserves exact per-layer cost accounting in ``cost_analysis`` —
 a lax.cond-based mixed stack would double-count both branches.
 """
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -87,56 +89,24 @@ def _ssm_layer(p: Params, x: jax.Array, cfg) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def _superblock_shape(cfg) -> Tuple[int, int]:
-    """(n_super, layers_per_super)."""
-    if cfg.local_global_ratio:
-        per = cfg.local_global_ratio + 1
-    elif cfg.attn_period:
-        per = cfg.attn_period
-    else:
-        per = 1
-    assert cfg.num_layers % per == 0, (cfg.num_layers, per)
-    return cfg.num_layers // per, per
-
-
 def _stack_init(key, cfg, dtype) -> Params:
-    n_super, per = _superblock_shape(cfg)
-
     def init_super(k):
-        ks = jax.random.split(k, per)
-        layers = []
-        for i in range(per):
-            kind = _mixer_kind(cfg, i, per)
-            layers.append(_layer_init(ks[i], cfg, dtype, kind=kind))
+        ks = jax.random.split(k, cfg.period)
         # same-kind layers within a super-block keep distinct pytree slots
-        return {f"l{i}": lp for i, lp in enumerate(layers)}
+        return {f"l{i}": _layer_init(ks[i], cfg, dtype, kind=cfg.layer(i).mixer)
+                for i in range(cfg.period)}
 
-    keys = jax.random.split(key, n_super)
+    keys = jax.random.split(key, cfg.superblocks)
     return jax.vmap(init_super)(keys)
 
 
-def _mixer_kind(cfg, i: int, per: int) -> str:
-    if cfg.family == "ssm":
-        return "ssm"
-    if cfg.attn_period:  # jamba: last layer of the period is attention
-        return "attn" if i == per - 1 else "ssm"
-    return "attn"
-
-
-def _layer_window(cfg, i: int, per: int) -> Optional[int]:
-    if cfg.local_global_ratio:
-        return cfg.sliding_window if i < cfg.local_global_ratio else None
-    return cfg.sliding_window
-
-
 def _super_apply(sp: Params, x: jax.Array, cfg) -> jax.Array:
-    _, per = _superblock_shape(cfg)
-    for i in range(per):
-        p = sp[f"l{i}"]
-        if _mixer_kind(cfg, i, per) == "ssm":
+    for i in range(cfg.period):
+        p, layer = sp[f"l{i}"], cfg.layer(i)
+        if layer.mixer == "ssm":
             x = _ssm_layer(p, x, cfg)
         else:
-            x = _attn_layer(p, x, cfg, window=_layer_window(cfg, i, per))
+            x = _attn_layer(p, x, cfg, window=layer.window)
     return x
 
 
@@ -224,35 +194,34 @@ def lm_loss(params: Params, batch: Dict[str, jax.Array], cfg) -> jax.Array:
 def cache_init(cfg, batch: int, max_seq: int) -> Params:
     """Per-super-block stacked caches matching the scan structure."""
     dtype = dtype_of(cfg)
-    n_super, per = _superblock_shape(cfg)
 
     def one(_):
         entry: Dict[str, Any] = {}
-        for i in range(per):
-            if _mixer_kind(cfg, i, per) == "ssm":
+        for i in range(cfg.period):
+            layer = cfg.layer(i)
+            if layer.mixer == "ssm":
                 entry[f"l{i}"] = ssm_mod.ssd_state_init(cfg, batch, dtype)
             else:
                 entry[f"l{i}"] = attn.cache_init(
-                    cfg, batch, max_seq, dtype, window=_layer_window(cfg, i, per)
+                    cfg, batch, max_seq, dtype, window=layer.window
                 )
         return entry
 
-    return jax.vmap(one)(jnp.arange(n_super))
+    return jax.vmap(one)(jnp.arange(cfg.superblocks))
 
 
 def _super_decode(sp, cache_sp, x, pos, cfg):
-    _, per = _superblock_shape(cfg)
     new_cache = {}
-    for i in range(per):
-        p, c = sp[f"l{i}"], cache_sp[f"l{i}"]
-        if _mixer_kind(cfg, i, per) == "ssm":
+    for i in range(cfg.period):
+        p, c, layer = sp[f"l{i}"], cache_sp[f"l{i}"], cfg.layer(i)
+        if layer.mixer == "ssm":
             h = rmsnorm(x, p["norm1"])
             y, c2 = ssm_mod.ssd_decode(p["ssm"], h, cfg, c)
             x = x + y
         else:
             h = rmsnorm(x, p["norm1"])
             y, c2 = attn.attn_decode(
-                p["attn"], h, cfg, c, pos, window=_layer_window(cfg, i, per)
+                p["attn"], h, cfg, c, pos, window=layer.window
             )
             x = x + y
         if cfg.family != "ssm":
@@ -294,13 +263,12 @@ def prefill(
     new caches; with ``expert_load`` also each layer's
     ``moe.expert_load`` (``[layers, 2]``; a model with experts only)."""
     x = _embed_inputs(params, batch, cfg)
-    n_super, per = _superblock_shape(cfg)
 
     def super_prefill(sp, cache_sp, x):
         new_cache, loads = {}, []
-        for i in range(per):
-            p, c = sp[f"l{i}"], cache_sp[f"l{i}"]
-            if _mixer_kind(cfg, i, per) == "ssm":
+        for i in range(cfg.period):
+            p, c, layer = sp[f"l{i}"], cache_sp[f"l{i}"], cfg.layer(i)
+            if layer.mixer == "ssm":
                 h = rmsnorm(x, p["norm1"])
                 xproj, z, Bm, Cm, dt = ssm_mod._inputs(p["ssm"], h, cfg)
                 A = -jnp.exp(p["ssm"]["A_log"])
@@ -319,7 +287,7 @@ def prefill(
             else:
                 h = rmsnorm(x, p["norm1"])
                 y, c2 = attn.attn_prefill(
-                    p["attn"], h, cfg, c, window=_layer_window(cfg, i, per)
+                    p["attn"], h, cfg, c, window=layer.window
                 )
                 x = x + y
             if expert_load:

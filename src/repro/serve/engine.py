@@ -50,9 +50,9 @@ class ServeEngine:
     solved plan's collectives — the same plan ``layout_plan`` places
     params with. Incremental decode (:meth:`generate`) runs through the
     compiled decode-step executable (:meth:`compiled_decode` — the
-    KV/SSM caches are first-class graph tensors, docs/serving.md) by
-    default; ``decode_mode="legacy"`` keeps the cache-carrying model
-    API path for parity checks. ``fuse=True`` runs the graph-level
+    KV/SSM caches are first-class graph tensors, docs/serving.md); the
+    model's own ``api.decode_step`` stays the reference it is checked
+    against. ``fuse=True`` runs the graph-level
     fusion passes (docs/passes.md) on both graphs before solving, so
     norm/elementwise/rope glue executes inside the adjacent kernels."""
 
@@ -66,7 +66,6 @@ class ServeEngine:
     force_schedule: Optional[Union[str, Mapping[str, str]]] = None
     mesh: Optional[Any] = None       # jax.sharding.Mesh
     layout_plan: Optional[Any] = None  # SolveResult | LayoutPlan | {name: AxeSpec}
-    decode_mode: str = "compiled"      # "compiled" | "legacy"
     fuse: bool = False                 # graph-level fusion passes (docs/passes.md)
 
     def __post_init__(self):
@@ -91,7 +90,6 @@ class ServeEngine:
         self.last_expert_load = None
         self._compiled: Dict[tuple, Any] = {}
         self._warned: set = set()
-        self._decode = self._scheduled(jax.jit(self.api.decode_step))
         if self.api.cfg.is_moe:
             def prefill(params, batch, cache):
                 return self.api.prefill(params, batch, cache, expert_load=True)
@@ -319,11 +317,9 @@ class ServeEngine:
     ) -> np.ndarray:
         """Greedy / temperature / top-k sampling for a fixed batch.
 
-        Prefill runs through the legacy full-sequence model API; each
-        decode step runs through the compiled decode executable
-        (``decode_mode="compiled"``, the default) or the legacy
-        ``api.decode_step`` (``decode_mode="legacy"``).
-        ``temperature``/``top_k`` override the engine defaults per call;
+        Prefill runs through the full-sequence model API; each decode
+        step runs through the compiled decode executable
+        (:meth:`decode_step`). ``temperature``/``top_k`` override the engine defaults per call;
         ``temperature=0`` (or unset with an engine default of 0) is
         exact greedy decoding."""
         assert self.params is not None, "call load() first"
@@ -343,18 +339,11 @@ class ServeEngine:
                            temperature=temperature, top_k=top_k)
         outs.append(tok)
         pos = s_prompt
-        compiled = self.decode_mode != "legacy"
         for i in range(max_new_tokens - 1):
             key, sub = jax.random.split(key)
-            if compiled:
-                step_logits, cache = self.decode_step(
-                    tok, cache, jnp.full((b,), pos, jnp.int32)
-                )
-            else:
-                logits, cache = self._decode(
-                    self.params, tok[:, None], cache, jnp.int32(pos)
-                )
-                step_logits = logits[:, -1]
+            step_logits, cache = self.decode_step(
+                tok, cache, jnp.full((b,), pos, jnp.int32)
+            )
             tok = self._sample(step_logits, sub,
                                temperature=temperature, top_k=top_k)
             outs.append(tok)

@@ -50,6 +50,39 @@ def test_prefill_decode_smoke(arch):
     assert jnp.all(jnp.isfinite(logits2)), arch
 
 
+#: the published layer structure of each preset: (super-block period,
+#: attention layers per period (by slot), windowed layers per period)
+LAYER_STRUCTURE = {
+    "dbrx-132b": (1, (0,), ()),
+    "qwen3-moe-235b-a22b": (1, (0,), ()),
+    "llava-next-mistral-7b": (1, (0,), ()),
+    "starcoder2-7b": (1, (0,), ()),
+    "gemma3-12b": (6, (0, 1, 2, 3, 4, 5), (0, 1, 2, 3, 4)),  # 5 local : 1 global
+    "qwen3-4b": (1, (0,), ()),
+    "mistral-nemo-12b": (1, (0,), ()),
+    "jamba-1.5-large-398b": (8, (7,), ()),  # 7 SSD + 1 attention
+    "whisper-large-v3": (1, (0,), ()),
+    "mamba2-2.7b": (1, (), ()),
+}
+
+
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_layer_structure_matches_published(arch):
+    """``ModelConfig.period``/``layer(i)``, which the model, its graphs
+    and their binding all read, give each preset's published structure at
+    full depth and in its smoke variant."""
+    period, attn, windowed = LAYER_STRUCTURE[arch]
+    for cfg in (get_config(arch), smoke_variant(get_config(arch))):
+        assert cfg.period == period
+        assert cfg.superblocks * period == cfg.num_layers
+        for i in range(cfg.num_layers):
+            layer = cfg.layer(i)
+            assert (layer.block, layer.slot) == divmod(i, period)
+            assert layer.mixer == ("attn" if layer.slot in attn else "ssm")
+            want = cfg.sliding_window if layer.slot in windowed else None
+            assert layer.window == want and (want or layer.slot not in windowed), (arch, i)
+
+
 def test_decode_matches_prefill_dense():
     """Decode-step logits must match a longer prefill's last logits."""
     cfg, api = _build("qwen3-4b")

@@ -21,8 +21,9 @@ import pytest
 from repro import axe
 from repro.axe import graphs as axe_graphs
 from repro.axe import rules as axe_rules
+from repro.axe.compile import SUPPORTED_FAMILIES
 from repro.axe.spec import AxeSpec, PhysicalSpace
-from repro.configs import get_config, smoke_variant
+from repro.configs import ARCH_IDS, get_config, smoke_variant
 from repro.models import ssm as ssm_mod
 from repro.models.model_zoo import build_model
 from repro.serve import ServeEngine
@@ -330,21 +331,124 @@ def test_compiled_forward_binding_unchanged():
 
 
 # ---------------------------------------------------------------------------
-# ServeEngine.generate: compiled decode is the default path
+# one owner for the stored format: the graph's records vs the pytrees
+# ---------------------------------------------------------------------------
+
+#: every preset with a compiled binding, at smoke size
+BOUND_ARCHS = tuple(a for a in ARCH_IDS
+                    if get_config(a).family in SUPPORTED_FAMILIES)
+
+
+def _stored_trees(cfg):
+    api = build_model(cfg)
+    params = api.init(jax.random.PRNGKey(0))
+    cache = api.cache_init(B, MAX_SEQ)
+    # distinct values in every cache entry, so a swapped layer shows
+    leaves, tree = jax.tree.flatten(cache)
+    keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
+    cache = jax.tree.unflatten(tree, [
+        jax.random.normal(k, x.shape, jnp.float32).astype(x.dtype)
+        for k, x in zip(keys, leaves)])
+    return params, cache
+
+
+def _decode_graph(cfg):
+    return axe_graphs.decode_graph(cfg, B, MAX_SEQ, PhysicalSpace(()),
+                                   dtype=cfg.dtype)
+
+
+@pytest.mark.parametrize("arch", BOUND_ARCHS)
+def test_graph_layers_match_stored_superblocks(arch):
+    """Each graph layer's mixer and window are those of the stored
+    super-block slot its records point at, and the layers cover every
+    (super-block, slot) entry of the stacked pytrees once."""
+    cfg = _cfg(arch)
+    params, cache = _stored_trees(cfg)
+    dec = _decode_graph(cfg)
+    fwd = axe_graphs.model_graph(cfg, B, 8, PhysicalSpace(()), dtype=cfg.dtype,
+                                 layers=cfg.num_layers)
+    nodes = {n.name: n for n in fwd.nodes + dec.nodes}
+    seen = set()
+    for i in range(cfg.num_layers):
+        rec = dec.aux[f"L{i}.norm1"]
+        assert rec == fwd.aux[f"L{i}.norm1"]
+        _, slot, _ = rec.path
+        seen.add((rec.block, slot))
+        stored = params["blocks"][slot]
+        mixer = "attn" if f"L{i}.decode_attention" in nodes else "ssm"
+        assert {"attn", "ssm"} & set(stored) == {mixer}
+        if mixer == "attn":
+            length = cache[slot]["k"].shape[2]
+            window = length if length < MAX_SEQ else None
+            assert nodes[f"L{i}.attention"].attr("window") == window
+            assert nodes[f"L{i}.decode_attention"].attr("ring") == (window is not None)
+    n_super = jax.tree.leaves(params["blocks"])[0].shape[0]
+    assert seen == {(b, s) for b in range(n_super) for s in params["blocks"]}
+
+
+@pytest.mark.parametrize("arch", BOUND_ARCHS)
+def test_graph_records_resolve_to_stored_leaves(arch):
+    """Every param/cache input (and auxiliary tensor) of the decode and
+    forward graphs names a leaf of ``init``/``cache_init``, and its view
+    of that leaf, plain or in place, has the input's shape and dtype."""
+    cfg = dataclasses.replace(_cfg(arch), dtype="bfloat16")
+    api = build_model(cfg)
+    params = jax.eval_shape(api.init, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: api.cache_init(B, MAX_SEQ))
+    trees = {"params": params, "cache": cache}
+    for g in (_decode_graph(cfg),
+              axe_graphs.model_graph(cfg, B, 8, PhysicalSpace(()), dtype=cfg.dtype,
+                                     layers=cfg.num_layers)):
+        stored = [m for m in g.inputs.values() if m.role != "activation"]
+        assert all(m.stored.tree == ("params" if m.role == "param" else "cache")
+                   for m in stored)
+        for r in [m.stored for m in stored] + list(g.aux.values()):
+            leaf = trees[r.tree]
+            for k in r.path:
+                leaf = leaf[k]
+            assert r.block is None or 0 <= r.block < leaf.shape[0], r
+        # plain arrays (model_inputs), and the in-place views decode_inputs binds
+        plain = jax.eval_shape(lambda p: axe.model_inputs(g, cfg, p), params)
+        binds = [(plain, [m for m in stored if m.role == "param"])]
+        if g.cache_out:
+            binds.append((jax.eval_shape(lambda p, c: axe.decode_inputs(g, cfg, p, c),
+                                         params, cache), stored))
+        for bound, metas in binds:
+            for m in metas:
+                assert (bound[m.name].shape, str(bound[m.name].dtype)) == (m.shape, m.dtype), m
+            assert set(g.aux) <= set(bound)
+
+
+@pytest.mark.parametrize("arch", BOUND_ARCHS)
+def test_decode_cache_inverts_decode_inputs(arch):
+    """``decode_cache`` puts each bound cache-in tensor back where it was
+    read from: the cache comes back unchanged, leaf for leaf."""
+    cfg = _cfg(arch)
+    params, cache = _stored_trees(cfg)
+    g = _decode_graph(cfg)
+    bound = axe.decode_inputs(g, cfg, params, cache)
+    assert set(g.cache_out.values()) == {n for n, m in g.inputs.items() if m.role == "cache"}
+    outs = [bound[g.cache_out[o]] if o in g.cache_out else None for o in g.outputs()]
+    back = axe.decode_cache(g, cfg, outs, cache)
+    assert jax.tree.structure(back) == jax.tree.structure(cache)
+    for got, want in zip(jax.tree.leaves(back), jax.tree.leaves(cache)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# ---------------------------------------------------------------------------
+# ServeEngine.generate: decode runs through the compiled step
 # ---------------------------------------------------------------------------
 
 _ENGINES = {}
 
 
-def _engine(arch, mode="compiled"):
-    key = (arch, mode)
-    if key not in _ENGINES:
+def _engine(arch):
+    if arch not in _ENGINES:
         cfg, api, params = _setup(arch)
-        eng = ServeEngine(api=api, batch_size=B, max_seq=MAX_SEQ,
-                          decode_mode=mode)
+        eng = ServeEngine(api=api, batch_size=B, max_seq=MAX_SEQ)
         eng.load(params)
-        _ENGINES[key] = eng
-    return _ENGINES[key]
+        _ENGINES[arch] = eng
+    return _ENGINES[arch]
 
 
 def _prompts(cfg, seed=1):
@@ -353,22 +457,30 @@ def _prompts(cfg, seed=1):
     )
 
 
+def _reference_generate(api, params, prompts, n):
+    """Greedy decoding through the model's own ``api.prefill`` /
+    ``api.decode_step``: the reference the compiled step answers to."""
+    logits, cache = api.prefill(params, {"tokens": prompts},
+                                api.cache_init(prompts.shape[0], MAX_SEQ))
+    tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+    outs, pos = [tok], prompts.shape[1]
+    for _ in range(n - 1):
+        logits, cache = api.decode_step(params, tok[:, None], cache, jnp.int32(pos))
+        tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        outs.append(tok)
+        pos += 1
+    return np.stack([np.asarray(t) for t in outs], axis=1)
+
+
 @pytest.mark.parametrize("arch", ARCHS)
 def test_generate_compiled_matches_legacy(arch):
-    """Full short generate, token-for-token: the compiled decode path
-    (the default) vs ``decode_mode="legacy"`` greedy."""
-    cfg, _, _ = _setup(arch)
+    """Full short generate, token-for-token: the engine's compiled decode
+    path vs greedy decoding through the model's own prefill/decode_step."""
+    cfg, api, params = _setup(arch)
     prompts = _prompts(cfg)
-    out_c = _engine(arch, "compiled").generate(prompts, 6)
-    out_l = _engine(arch, "legacy").generate(prompts, 6)
+    out_c = _engine(arch).generate(prompts, 6)
     assert out_c.shape == (B, 6)
-    np.testing.assert_array_equal(out_c, out_l)
-
-
-def test_generate_default_mode_is_compiled():
-    eng = _engine("qwen3-4b", "compiled")
-    assert eng.decode_mode == "compiled"
-    assert ServeEngine.__dataclass_fields__["decode_mode"].default == "compiled"
+    np.testing.assert_array_equal(out_c, _reference_generate(api, params, prompts, 6))
 
 
 def test_generate_temperature_zero_is_greedy():
@@ -376,7 +488,7 @@ def test_generate_temperature_zero_is_greedy():
     greedy run exactly; ``top_k=1`` does too at any temperature."""
     cfg, _, _ = _setup("qwen3-4b")
     prompts = _prompts(cfg)
-    eng = _engine("qwen3-4b", "compiled")
+    eng = _engine("qwen3-4b")
     greedy = eng.generate(prompts, 6)
     np.testing.assert_array_equal(greedy,
                                   eng.generate(prompts, 6, temperature=0.0))
@@ -389,7 +501,7 @@ def test_generate_top_k_restricts_support():
     """Sampled ids at temperature>0 with top_k=k always come from the
     top-k of the greedy path's logits support — checked at the
     _sample level for a fixed logits row."""
-    eng = _engine("qwen3-4b", "compiled")
+    eng = _engine("qwen3-4b")
     logits = jnp.asarray([[0.0, 3.0, 1.0, 2.0, -1.0]] * 4)
     allowed = {1, 3}  # top-2 ids
     for seed in range(5):
